@@ -27,13 +27,9 @@ import jax, jax.numpy as jnp, re
 from jax.sharding import PartitionSpec as P
 from repro.distributed.collectives import compressed_psum
 
-# jax moved shard_map out of jax.experimental at some versions; take
-# whichever this jax provides (mirrors repro.distributed.pipeline)
-shard_map = getattr(jax, 'shard_map', None)
-if shard_map is None:
-    from jax.experimental.shard_map import shard_map
-
-mesh = jax.make_mesh((2,), ('pod',))  # the production pod axis
+shard_map = jax.shard_map
+# the production pod axis
+mesh = jax.make_mesh((2,), ('pod',), axis_types=(jax.sharding.AxisType.Auto,))
 x = jax.ShapeDtypeStruct((2, 4096), jnp.float32)
 
 def wire_bytes(fn):
@@ -66,6 +62,7 @@ def run(reps: int = 200) -> list[Dist]:
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(
         os.environ,
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=2",
         PYTHONPATH=os.path.join(repo, "src"),
     )

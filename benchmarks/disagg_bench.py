@@ -135,6 +135,7 @@ def disagg_comparison(
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(
         os.environ,
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
         PYTHONPATH=os.path.join(repo, "src"),
     )

@@ -124,7 +124,6 @@ class BranchChanger:
             lowered = jax.jit(fn, **self._jit_kwargs).lower(
                 *_tree_avals(example_args), **lower_kwargs
             )
-            # out_info lives on the Lowered object in jax 0.4.x
             shapes = jax.tree.map(
                 lambda x: (tuple(x.shape), str(x.dtype)), lowered.out_info
             )

@@ -27,17 +27,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs import ArchConfig
 
-# jax moved shard_map from jax.experimental to the top-level namespace; the
-# pinned 0.4.x here only has the experimental spelling.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:  # pragma: no cover - depends on jax version
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# lax.pvary only exists on jax versions whose shard_map tracks varying manual
-# axes; older shard_map treats every value as varying, so identity is correct.
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-
-
 def split_stages(cfg: ArchConfig, num_stages: int) -> int:
     """Layers per stage; requires an even split of period-groups."""
     m = cfg.num_layers // cfg.period
@@ -93,7 +82,9 @@ def pipeline_forward(
             emit = jnp.where(stage_id == num_stages - 1, y, jnp.zeros_like(y))
             return y_next, (out_idx, emit)
 
-        buf0 = _pvary(jnp.zeros(mb_shape, xs.dtype), (stage_axis,))
+        buf0 = jax.lax.pcast(
+            jnp.zeros(mb_shape, xs.dtype), (stage_axis,), to="varying"
+        )
         _, (idxs, emits) = jax.lax.scan(
             tick, buf0, jnp.arange(ticks)
         )
@@ -111,7 +102,7 @@ def pipeline_forward(
             stage_axis,
         )
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(P(stage_axis), P()),

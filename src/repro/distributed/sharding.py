@@ -24,7 +24,7 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs import ArchConfig
 
@@ -392,12 +392,17 @@ class MeshPlan:
                     f"--xla_force_host_platform_device_count=N for CPU runs)"
                 )
             if self.offset == 0:
-                self._mesh = jax.make_mesh((self.dp, self.mp), SERVING_AXES)
+                self._mesh = jax.make_mesh(
+                    (self.dp, self.mp), SERVING_AXES,
+                    axis_types=(AxisType.Auto,) * 2,
+                )
             else:
                 devs = np.asarray(
                     jax.devices()[self.offset : self.offset + self.num_devices]
                 ).reshape(self.dp, self.mp)
-                self._mesh = Mesh(devs, SERVING_AXES)
+                self._mesh = Mesh(
+                    devs, SERVING_AXES, axis_types=(AxisType.Auto,) * 2
+                )
         return self._mesh
 
     # --- spec builders (all return NamedSharding trees / values) ---

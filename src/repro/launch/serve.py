@@ -21,6 +21,7 @@ from repro import models
 from repro.configs import get_config
 from repro.core.faults import FaultPlan
 from repro.core.telemetry import Telemetry
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.admission import SHED_POLICIES
 from repro.runtime.scheduler import (
     attach_distinct_prompts,
@@ -160,6 +161,7 @@ def _print_report(rep: dict) -> None:
 
 
 def main(argv: list[str] | None = None) -> dict:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
     ap.add_argument("--smoke", action="store_true")
@@ -390,7 +392,8 @@ def main(argv: list[str] | None = None) -> dict:
     # Every engine run is close-guarded and the whole sweep is
     # interrupt-guarded: a Ctrl-C mid-stream keeps the reports of every
     # completed engine and still flushes the telemetry artifacts
-    # (--trace-out/--metrics-out/--compile-report) on the way out.
+    # (--trace-out/--metrics-out/--compile-report) on the way out, then
+    # exits 130: an interrupted run never reports success.
     reports = {}
     interrupted = False
     try:
@@ -525,6 +528,8 @@ def main(argv: list[str] | None = None) -> dict:
                 f"{b['compiles_after_warmup']}",
                 flush=True,
             )
+    if interrupted:
+        raise SystemExit(130)  # a cut-short run is not a success
     return reports
 
 

@@ -6,8 +6,10 @@ Two implementations selectable as a *semi-static* choice (DESIGN.md §2):
   * ``chunked`` — lax.scan over KV blocks with online softmax (flash-style data
                   movement in pure JAX; the beyond-paper memory-term optimisation)
 
-On real TPU hardware the Pallas kernels in ``repro.kernels`` replace both; the
-dry-run compiles the pure-JAX paths (Pallas is validated in interpret mode).
+These pure-JAX paths are what runs everywhere, the TPU included: nothing
+here calls the Pallas kernels in ``repro.kernels``, which are tested in
+interpret mode and compiled for a described chip by
+``tests/test_tpu_compile.py``.
 """
 
 from __future__ import annotations
@@ -467,9 +469,10 @@ def paged_decode_attention(
     SDPA tail. The branch is on the cache's abstract dtype — trace-time,
     one executable per ``kv_dtype`` coordinate, never a hot-loop check.
 
-    On TPU the gather+SDPA lowers to ``kernels.paged_decode_attention``
-    (or its ``_int8`` variant; block-table indirection in the index map);
-    this pure-jax path is its oracle and the CPU/dry-run implementation.
+    This pure-JAX gather + SDPA is what runs on every backend, the TPU
+    included. ``kernels.paged_decode_attention`` computes the same thing
+    in place but is not called from here: the TPU compiler refuses its
+    page block (``tests/test_tpu_compile.py``).
     """
     b = x.shape[0]
     pos = jnp.asarray(pos, jnp.int32)

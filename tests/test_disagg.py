@@ -36,6 +36,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _run(code: str, devices: int = 2) -> str:
     env = dict(
         os.environ,
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
         PYTHONPATH=os.path.join(REPO, "src"),
     )

@@ -16,6 +16,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _run(code: str, devices: int = 8) -> str:
     env = dict(
         os.environ,
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
         PYTHONPATH=os.path.join(REPO, "src"),
     )
@@ -38,7 +39,8 @@ def test_sharded_train_step_matches_single_device():
         from repro.distributed import sharding as shd
 
         cfg = get_config('olmo-1b').smoke()
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = jax.make_mesh((4, 2), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         shape = ShapeSpec('t', 'train', 32, 8)
         lowered = steps.lower_for(cfg, mesh, shape, donate=False)
         exe = lowered.compile()
@@ -69,7 +71,8 @@ def test_decode_step_sharded_cache():
         from repro.runtime import steps
 
         cfg = get_config('gemma2-27b').smoke()
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = jax.make_mesh((4, 2), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         shape = ShapeSpec('d', 'decode', 32, 8)
         exe = steps.lower_for(cfg, mesh, shape, donate=False).compile()
         params = models.init_params(cfg, jax.random.PRNGKey(0))
@@ -91,15 +94,11 @@ def test_compressed_psum_int8_wire():
         from jax.sharding import PartitionSpec as P
         from repro.distributed.collectives import compressed_psum
 
-        # version-portable shard_map (mirrors repro.distributed.pipeline)
-        shard_map = getattr(jax, 'shard_map', None)
-        if shard_map is None:
-            from jax.experimental.shard_map import shard_map
-
-        mesh = jax.make_mesh((8,), ('pod',))
+        mesh = jax.make_mesh((8,), ('pod',),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         @jax.jit
         def f(x):
-            return shard_map(
+            return jax.shard_map(
                 lambda s: compressed_psum(s, 'pod'),
                 mesh=mesh, in_specs=P('pod'), out_specs=P('pod'),
             )(x)
@@ -131,21 +130,24 @@ def test_multipod_mesh_axes():
     assert "OK" in out
 
 
-def test_dryrun_cell_end_to_end_small_arch():
-    """The actual dry-run entry point, production mesh, real arch."""
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+def test_dryrun_cell_end_to_end_small_arch(tmp_path):
+    """The actual dry-run entry point, production mesh, real arch. The
+    output directory is fresh: the dry run skips cells it finds on disk."""
+    env = dict(
+        os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.path.join(REPO, "src")
+    )
     out = subprocess.run(
         [
             sys.executable, "-m", "repro.launch.dryrun",
             "--arch", "mamba2-370m", "--shape", "decode_32k",
-            "--mesh", "multi", "--out", "/tmp/test-dryrun",
+            "--mesh", "multi", "--out", str(tmp_path),
             "--tag", "pytest",
         ],
         env=env, capture_output=True, text=True, timeout=900, cwd=REPO,
     )
     assert out.returncode == 0, out.stderr[-2000:]
-    rec = json.load(
-        open("/tmp/test-dryrun/mamba2-370m--decode_32k--multi-pytest.json")
+    rec = json.loads(
+        (tmp_path / "mamba2-370m--decode_32k--multi-pytest.json").read_text()
     )
     assert rec["status"] == "ok"
     assert rec["chips"] == 512
@@ -164,8 +166,10 @@ def test_elastic_remesh_checkpoint_restore():
         from repro.runtime import steps
 
         cfg = get_config('olmo-1b').smoke()
-        big = jax.make_mesh((4, 2), ('data', 'model'))
+        big = jax.make_mesh((4, 2), ('data', 'model'),
+                            axis_types=(jax.sharding.AxisType.Auto,) * 2)
         small = jax.make_mesh((2, 2), ('data', 'model'),
+                              axis_types=(jax.sharding.AxisType.Auto,) * 2,
                               devices=jax.devices()[:4])
 
         params = models.init_params(cfg, jax.random.PRNGKey(0))

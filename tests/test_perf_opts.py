@@ -85,6 +85,7 @@ def test_seq_fallback_semantics_on_mesh():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(
         os.environ,
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
         PYTHONPATH=os.path.join(repo, "src"),
     )
@@ -97,7 +98,8 @@ def test_seq_fallback_semantics_on_mesh():
         # qwen3 family: heads (4) don't divide the model axis (8)
         cfg = dataclasses.replace(get_config('qwen3-14b').smoke(),
                                   num_heads=4, num_kv_heads=2)
-        mesh = jax.make_mesh((1, 8), ('data', 'model'))
+        mesh = jax.make_mesh((1, 8), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         shape = ShapeSpec('p', 'prefill', 32, 8)
         params = models.init_params(cfg, jax.random.PRNGKey(0))
         tok = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0,

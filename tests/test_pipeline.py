@@ -18,6 +18,7 @@ def test_bubble_fraction():
 def test_pipeline_matches_sequential_oracle():
     env = dict(
         os.environ,
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=4",
         PYTHONPATH=os.path.join(REPO, "src"),
     )
@@ -26,7 +27,8 @@ def test_pipeline_matches_sequential_oracle():
         from repro.distributed.pipeline import (
             pipeline_forward, reference_forward)
 
-        mesh = jax.make_mesh((4,), ('stage',))
+        mesh = jax.make_mesh((4,), ('stage',),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         S, M, mb, d = 4, 8, 2, 16
 
         def stage_fn(sp, x):

@@ -16,9 +16,8 @@ from repro.configs import ASSIGNED, get_config
 from repro.distributed import sharding as shd
 from repro.runtime import steps
 
-# jax 0.4.x AbstractMesh signature: a tuple of (axis_name, size) pairs.
-SINGLE = AbstractMesh((("data", 16), ("model", 16)))
-MULTI = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+SINGLE = AbstractMesh((16, 16), ("data", "model"))
+MULTI = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _axes_of(spec_entry):
@@ -145,6 +144,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _run(code: str, devices: int = 4) -> str:
     env = dict(
         os.environ,
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
         PYTHONPATH=os.path.join(REPO, "src"),
     )
