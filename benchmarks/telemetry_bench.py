@@ -7,9 +7,10 @@ This bench measures that tax at two levels and writes
 ``BENCH_telemetry.json`` for ``scripts/bench_check.py`` to gate:
 
 * **micro**: the per-call-site cost of the disabled path (guard + counter
-  + histogram observe), scaled by the instrumentation density of one
-  serving step and compared against the measured step time — the
-  disabled-path overhead estimate must stay under 1%.
+  + histogram observe) and of a phase span nothing records, scaled by the
+  instrumentation density of one serving step and compared against the
+  measured step time — the disabled-path overhead estimate must stay
+  under 1%.
 * **macro**: the same saturated greedy/sample stream BENCH_serving.json
   drives, run tracing-off and tracing-on, sync and async. Tracing-on must
   hold >= 95% of tracing-off throughput, greedy token streams must be
@@ -38,6 +39,9 @@ from repro.runtime.serve import Engine, EngineConfig
 # note, finish check, d2h pull, async bookkeeping) — a deliberate
 # overestimate so the micro gate errs strict.
 SITES_PER_STEP = 8
+# Phase spans one dense decode step opens while nothing records them: the
+# step, the executable call and its three pulls.
+SPANS_PER_STEP = 5
 
 
 def disabled_site_ns(reps: int = 200_000) -> float:
@@ -57,6 +61,20 @@ def disabled_site_ns(reps: int = 200_000) -> float:
                 pass
             c.inc()
             h.observe(0.123)
+        samples.append((time.perf_counter_ns() - t0) / reps)
+    return float(np.median(samples))
+
+
+def disabled_span_ns(reps: int = 200_000) -> float:
+    """Median cost of one phase span with a note while neither the recorder
+    nor the profiler records it (the shared no-op span)."""
+    span = Telemetry().span
+    samples = []
+    for _ in range(5):
+        t0 = time.perf_counter_ns()
+        for _ in range(reps):
+            with span("serve.dispatch.pull") as sp:
+                sp.note(bytes=8)
         samples.append((time.perf_counter_ns() - t0) / reps)
     return float(np.median(samples))
 
@@ -137,11 +155,14 @@ def telemetry_comparison(
 
     # Micro: disabled-path tax per step vs the measured step time.
     site_ns = disabled_site_ns(20_000)
+    phase_ns = disabled_span_ns(20_000)
     off = arms["off_sync"]
     steps = max(1, off.get("steps", 1))
     span_ns = off.get("span_s", 0.0) * 1e9
     step_ns = span_ns / steps if span_ns else float("inf")
-    off_overhead_frac = (site_ns * SITES_PER_STEP) / step_ns
+    off_overhead_frac = (
+        site_ns * SITES_PER_STEP + phase_ns * SPANS_PER_STEP
+    ) / step_ns
 
     ratios = {}
     identical = {}
@@ -185,6 +206,8 @@ def telemetry_comparison(
             "repeats": repeats,
             "sites_per_step": SITES_PER_STEP,
             "disabled_site_ns": round(site_ns, 1),
+            "spans_per_step": SPANS_PER_STEP,
+            "disabled_span_ns": round(phase_ns, 1),
             "step_ns": round(step_ns, 1),
         },
         **{k: v for k, v in arms.items()},

@@ -22,6 +22,17 @@ complementary surfaces share one namespace:
   agree by construction. Snapshots serialise to JSON and to Prometheus
   text exposition format (``to_prometheus``).
 
+* ``span`` — the phase spans of the serving step, named
+  ``serve.<layer>.<phase>`` with the layer one of ``SPAN_LAYERS``. One
+  primitive, two sinks, neither switched by an option of its own: while
+  ``jax.profiler`` collects, a span opens a ``TraceAnnotation`` of the same
+  name and metadata, which lands on the profiler's host plane on the device
+  trace's clock, so each idle gap of the chip can be put down to the phase
+  the host was in; while the flight recorder is enabled, the span also lands
+  there as an ``X`` event on the ``scheduler`` track (the recorder's own
+  ``perf_counter_ns`` clock, not the device's). With both off, ``span``
+  returns the shared no-op ``NO_SPAN`` after one check.
+
 ``Telemetry`` bundles the two plus the per-DispatchKey compile reports
 (``hlo_analysis`` wiring, satellite of PR 7) and is what ``Engine``,
 batchers, and ``PagePool`` accept.
@@ -35,9 +46,15 @@ import time
 from bisect import bisect_left
 from typing import Any
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "Event",
     "FlightRecorder",
+    "NO_SPAN",
+    "SPAN_LAYERS",
+    "SPAN_TRACK",
+    "span",
     "Counter",
     "Gauge",
     "Histogram",
@@ -167,17 +184,88 @@ class FlightRecorder:
                 if e is not None
             ]
 
-    def tracks(self) -> list[str]:
-        seen: dict[str, None] = {}
-        for ev in self.events():
-            seen.setdefault(ev.track, None)
-        return list(seen)
-
     def clear(self) -> None:
         with self._lock:
             self._buf = [None] * self.capacity
             self._next = 0
             self.t0_ns = time.perf_counter_ns()
+
+
+# ------------------------------------------------------------------- spans
+# The layers a ``serve.<layer>.<phase>`` span may name: the step loop's
+# planning and bookkeeping, the calls into the device (uploads, executable
+# launches, pulls), and the paged KV cache manager.
+SPAN_LAYERS = ("batcher", "dispatch", "cache")
+SPAN_TRACK = "scheduler"  # the recorder track every phase span lands on
+
+_profiling = TraceAnnotation.is_enabled
+
+
+class _NoSpan:
+    """The span handed out while nothing records: enter, note and exit do
+    nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        return False
+
+    def note(self, **meta) -> None:
+        pass
+
+
+NO_SPAN = _NoSpan()
+
+
+class _Span:
+    """An open phase span: a profiler annotation while the profiler
+    collects, a recorder ``X`` event when ``rec`` is given, or both.
+    ``note`` adds metadata counted at the span's close."""
+
+    __slots__ = ("name", "rec", "args", "ann", "t0_ns")
+
+    def __init__(self, name: str, rec: FlightRecorder | None, args: dict):
+        self.name = name
+        self.rec = rec
+        self.args = args
+        self.ann = None
+        self.t0_ns = 0
+
+    def __enter__(self) -> "_Span":
+        if _profiling():
+            self.ann = TraceAnnotation(self.name, **self.args)
+            self.ann.__enter__()
+        self.t0_ns = time.perf_counter_ns()
+        return self
+
+    def note(self, **meta) -> None:
+        self.args.update(meta)
+        if self.ann is not None:
+            self.ann.set_metadata(**meta)
+
+    def __exit__(self, *exc) -> bool:
+        if self.rec is not None:
+            self.rec.emit(
+                self.name, SPAN_TRACK, PH_SPAN, ts_ns=self.t0_ns,
+                dur_ns=time.perf_counter_ns() - self.t0_ns,
+                args=self.args or None,
+            )
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        return False
+
+
+def span(name: str, recorder: FlightRecorder | None = None, **meta):
+    """A context manager around one phase of the serving step, named
+    ``serve.<layer>.<phase>``: a profiler annotation while ``jax.profiler``
+    collects, a recorder span while ``recorder`` is enabled, else the shared
+    ``NO_SPAN``."""
+    if recorder is not None and recorder.enabled:
+        return _Span(name, recorder, meta)
+    return _Span(name, None, meta) if _profiling() else NO_SPAN
 
 
 # ---------------------------------------------------------------- instruments
@@ -519,6 +607,15 @@ class Telemetry:
     def trace_or_none(self) -> FlightRecorder | None:
         """The call-site guard: hold the recorder only when it records."""
         return self.recorder if self.recorder.enabled else None
+
+    def span(self, name: str, **meta):
+        """A phase span (module-level ``span``) over this recorder; the
+        check is repeated here, not delegated, because it runs on every
+        phase of every step."""
+        rec = self.recorder
+        if rec.enabled:
+            return _Span(name, rec, meta)
+        return _Span(name, None, meta) if _profiling() else NO_SPAN
 
     def metrics_json(self) -> str:
         return json.dumps(self.registry.snapshot(), indent=2, sort_keys=True)

@@ -615,11 +615,13 @@ class _MultiLaneMixin:
         Runs before ``stats`` is built so the batcher's counters land in
         the engine's registry. ``_trace`` is None unless the flight
         recorder is enabled — every hot-path emit site guards on that one
-        compare, which is the whole disabled-path overhead. The request-
-        phase histograms are cached as plain attributes (one bisect per
-        observation, no registry lookup per token)."""
+        compare, which is the whole disabled-path overhead. ``_span`` opens
+        the step's ``serve.<layer>.<phase>`` spans (DESIGN.md §14). The
+        request-phase histograms are cached as plain attributes (one bisect
+        per observation, no registry lookup per token)."""
         self.telemetry = telemetry or Telemetry()
         self._trace = self.telemetry.trace_or_none()
+        self._span = self.telemetry.span  # phase spans (core.telemetry)
         reg = self.telemetry.registry
         self._h_qwait = reg.histogram("queue_wait_ms")
         self._h_ttft = reg.histogram("ttft_ms")
@@ -627,11 +629,19 @@ class _MultiLaneMixin:
         self._h_e2e = reg.histogram("request_latency_ms")
         self._lane_hist: dict[str, Any] = {}
 
+    def _issue(self, lane: str, t0_ns: int, exe: Callable, *args) -> Any:
+        """Call a lane's executable under its ``serve.dispatch.issue`` span,
+        then observe the lane's call latency from ``t0_ns``."""
+        with self._span("serve.dispatch.issue", lane=lane):
+            out = exe(*args)
+        self._lane_tick(lane, t0_ns)
+        return out
+
     def _lane_tick(self, lane: str, t0_ns: int) -> None:
-        """Per-lane executable-call latency, anchored at ``t0_ns`` (taken
-        just before the lane call): the ``lane_step_ms{lane=...}``
-        histogram always observes (the per-lane Prometheus surface); a
-        span lands on the lane's trace track only when recording."""
+        """Per-lane executable-call latency into the always-on
+        ``lane_step_ms{lane=...}`` histogram (the per-lane Prometheus
+        surface), anchored at ``t0_ns``: taken just before the prefill
+        call, and before the decode call's mirror uploads."""
         dt_ns = time.perf_counter_ns() - t0_ns
         h = self._lane_hist.get(lane)
         if h is None:
@@ -646,10 +656,6 @@ class _MultiLaneMixin:
                 "lane_step_ms", **labels
             )
         h.observe(dt_ns / 1e6)
-        tr = self._trace
-        if tr is not None:
-            tr.emit("lane_step", "lane:" + lane, ph="X", ts_ns=t0_ns,
-                    dur_ns=dt_ns)
 
     def _note_admit(self, req: Request, now: float) -> None:
         """Queue-wait histogram + admission lifecycle event."""
@@ -755,7 +761,7 @@ class _MultiLaneMixin:
         goes through ``steps.pull_host`` so ``device_wait_ms`` measures
         exactly how long the host sat blocked on the device,
         ``d2h_transfers`` counts every transfer the step loop actually
-        paid for, and (when recording) each pull lands as a "d2h" span."""
+        paid for, and each pull is a ``serve.dispatch.pull`` span."""
         if self._faults is not None:
             f = self._faults.fire("d2h_stall")
             if f is not None:
@@ -782,33 +788,34 @@ class _MultiLaneMixin:
         device-visible call sequence — and therefore every token stream —
         is identical to the synchronous loop.
         """
-        t0 = time.perf_counter()
-        dw0 = self.stats.device_wait_ms
-        if self._has_deadlines:
-            self._cancel_overdue(now)
-        finished = self._backlog
-        self._backlog = []
-        ran_ahead = False
-        if self.async_steps and self._inflight:
-            if self._can_run_ahead():
-                finished.extend(self._run_ahead(now))
-                ran_ahead = True
-            else:
-                finished.extend(self._commit_pending(now))
-        if not ran_ahead:
-            finished.extend(self._step_impl(now))
-        # depth limit: commit oldest-first until the queue fits the
-        # configured pipeline capacity (0 in synchronous mode — the lanes
-        # never park there, so this loop is a no-op)
-        limit = self.async_depth - 1 if self.async_steps else 0
-        while len(self._inflight) > limit:
-            finished.extend(self._commit_oldest(now))
-        self.stats.host_plan_ms += (
-            (time.perf_counter() - t0) * 1e3
-            - (self.stats.device_wait_ms - dw0)
-        )
-        if self._watchdog is not None:
-            self._watchdog_tick(time.perf_counter() - t0)
+        with self._span("serve.batcher.step", step=self.stats.steps):
+            t0 = time.perf_counter()
+            dw0 = self.stats.device_wait_ms
+            if self._has_deadlines:
+                self._cancel_overdue(now)
+            finished = self._backlog
+            self._backlog = []
+            ran_ahead = False
+            if self.async_steps and self._inflight:
+                if self._can_run_ahead():
+                    finished.extend(self._run_ahead(now))
+                    ran_ahead = True
+                else:
+                    finished.extend(self._commit_pending(now))
+            if not ran_ahead:
+                finished.extend(self._step_impl(now))
+            # depth limit: commit oldest-first until the queue fits the
+            # configured pipeline capacity (0 in synchronous mode — the
+            # lanes never park there, so this loop is a no-op)
+            limit = self.async_depth - 1 if self.async_steps else 0
+            while len(self._inflight) > limit:
+                finished.extend(self._commit_oldest(now))
+            self.stats.host_plan_ms += (
+                (time.perf_counter() - t0) * 1e3
+                - (self.stats.device_wait_ms - dw0)
+            )
+            if self._watchdog is not None:
+                self._watchdog_tick(time.perf_counter() - t0)
         return finished
 
     def flush(self, now: float = 0.0) -> list[Request]:
@@ -887,7 +894,8 @@ class _MultiLaneMixin:
             return finished
         # the parked steps are still in flight: stage any upkeep-touched
         # coordinate arrays now so their uploads ride their execution
-        self._preload_step_inputs()
+        with self._span("serve.dispatch.upload", lane="preload"):
+            self._preload_step_inputs()
         if prefilling:
             finished.extend(self._prefill_step(now, plan.chunk_budget))
             decoding = self._active & ~self._prefilling
@@ -1023,18 +1031,21 @@ class _MultiLaneMixin:
         eligibility (greedy, past teacher forcing, >= 2 tokens still to
         emit) is computed here on the cold path; per-slot verify windows
         are clamped later as data."""
-        decoding = self._active & ~self._prefilling
-        max_rem = 0
-        if self._spec_on:
-            for s, req in enumerate(self._slots):
-                if req is None or not decoding[s] or not req.greedy:
-                    continue
-                if self._cursor[s] + 1 < len(req.effective_prompt):
-                    continue  # still teacher-forcing prompt tokens
-                max_rem = max(max_rem, req.new_tokens - len(req.tokens))
-        return self._lane_policy.plan(
-            n_decode=int(decoding.sum()), max_remaining=max_rem
-        )
+        with self._span("serve.batcher.plan") as sp:
+            decoding = self._active & ~self._prefilling
+            max_rem = 0
+            if self._spec_on:
+                for s, req in enumerate(self._slots):
+                    if req is None or not decoding[s] or not req.greedy:
+                        continue
+                    if self._cursor[s] + 1 < len(req.effective_prompt):
+                        continue  # still teacher-forcing prompt tokens
+                    max_rem = max(max_rem, req.new_tokens - len(req.tokens))
+            plan = self._lane_policy.plan(
+                n_decode=int(decoding.sum()), max_remaining=max_rem
+            )
+            sp.note(budget=plan.chunk_budget, k=plan.k)
+        return plan
 
     def _plan_chunks(
         self, budget_left: int, *, limit: int | None = None
@@ -1048,28 +1059,39 @@ class _MultiLaneMixin:
         budget. Pure planning, no side effects: a chunk aborted by
         preemption records nothing. Returns [(slot, cursor, chunk), ...].
         """
-        order = sorted(
-            (
-                s for s in range(self.num_slots)
-                if self._prefilling[s] and self._active[s]
-            ),
-            key=lambda s: (self._slots[s].t_admit or 0.0, s),
-        )
-        out: list[tuple[int, int, int]] = []
-        for s in order:
-            if out and (budget_left < 1 or (limit and len(out) >= limit)):
-                break
-            req = self._slots[s]
-            prompt = req.effective_prompt
-            cursor = int(self._cursor[s])
-            remaining = len(prompt) - cursor
-            chunk = max(1, min(remaining, budget_left, self.prefill_chunk))
-            if chunk == remaining and chunk + 1 > budget_left and remaining > 1:
-                # a flipping slot also decodes its first token this step;
-                # shrink the final chunk so that token stays in budget
-                chunk -= 1
-            out.append((s, cursor, chunk))
-            budget_left -= chunk + (1 if chunk == remaining else 0)
+        with self._span("serve.batcher.plan", budget=budget_left) as sp:
+            order = sorted(
+                (
+                    s for s in range(self.num_slots)
+                    if self._prefilling[s] and self._active[s]
+                ),
+                key=lambda s: (self._slots[s].t_admit or 0.0, s),
+            )
+            out: list[tuple[int, int, int]] = []
+            for s in order:
+                if out and (
+                    budget_left < 1 or (limit and len(out) >= limit)
+                ):
+                    break
+                req = self._slots[s]
+                prompt = req.effective_prompt
+                cursor = int(self._cursor[s])
+                remaining = len(prompt) - cursor
+                chunk = max(
+                    1, min(remaining, budget_left, self.prefill_chunk)
+                )
+                if (
+                    chunk == remaining
+                    and chunk + 1 > budget_left
+                    and remaining > 1
+                ):
+                    # a flipping slot also decodes its first token this
+                    # step; shrink the final chunk so that token stays in
+                    # budget
+                    chunk -= 1
+                out.append((s, cursor, chunk))
+                budget_left -= chunk + (1 if chunk == remaining else 0)
+            sp.note(slots=len(out))
         return out
 
     def _note_chunk_bucket(self, bucket: int) -> None:
@@ -1123,7 +1145,10 @@ class _MultiLaneMixin:
         streams are untouched."""
         step = self._draft_dispatch(k)  # cold: slot-hit unless k moved
         t0_ns = time.perf_counter_ns()
-        drafts, self._draft_cache, _, _ = step(
+        drafts, self._draft_cache, _, _ = self._issue(
+            "dr",
+            t0_ns,
+            step,
             self._draft_cache,
             self._mirror.get("tok", self._tok),
             self._mirror.get("pos", self._pos),
@@ -1132,7 +1157,6 @@ class _MultiLaneMixin:
             self._mirror.get("spec_greedy", np.ones(self.num_slots, bool)),
             self._mirror.get("keys", self._keys),
         )
-        self._lane_tick("dr", t0_ns)
         self.stats.draft_steps += 1
         self.stats.note_lane("dr")
         # an inherent sync point: the host packs the verify windows from
@@ -1178,8 +1202,11 @@ class _MultiLaneMixin:
         )
         tok = self._pack_verify_tok(drafts, lengths, k)
         t0_ns = time.perf_counter_ns()
-        rows, nxt0, keys = self._verify_call(k, tok, lengths)
-        self._lane_tick(self._verify_lane, t0_ns)
+        # the verify hook packs its tables and uploads its windows inside
+        # the issue span
+        rows, nxt0, keys = self._issue(
+            self._verify_lane, t0_ns, self._verify_call, k, tok, lengths
+        )
         self.stats.verify_steps += 1
         self.stats.note_lane(self._verify_lane)
         self._mirror.put("keys", keys)
@@ -1783,7 +1810,10 @@ class ContinuousBatcher(_MultiLaneMixin):
             else jnp.asarray(self._keys)
         )
         t0_ns = time.perf_counter_ns()
-        nxt, self._cache, new_keys = step(
+        nxt, self._cache, new_keys = self._issue(
+            self._prefill_lane,
+            t0_ns,
+            step,
             self._cache,
             tok_dev,
             start_dev,
@@ -1792,7 +1822,6 @@ class ContinuousBatcher(_MultiLaneMixin):
             self._mirror.get("greedy", self._greedy),
             keys_dev,
         )
-        self._lane_tick(self._prefill_lane, t0_ns)
         # draft mirror (DESIGN.md §11): the draft stack must ingest the
         # same prompt windows so its KV tracks the committed stream before
         # the draft lane runs; the inputs are the target call's device
@@ -1802,7 +1831,10 @@ class ContinuousBatcher(_MultiLaneMixin):
             dstep = self._draft_prefill_dispatch(bucket)
             self.stats.note_lane("drp")
             t0_ns = time.perf_counter_ns()
-            _, self._draft_cache, _ = dstep(
+            _, self._draft_cache, _ = self._issue(
+                "drp",
+                t0_ns,
+                dstep,
                 self._draft_cache,
                 tok_dev,
                 start_dev,
@@ -1811,7 +1843,6 @@ class ContinuousBatcher(_MultiLaneMixin):
                 self._mirror.get("greedy", self._greedy),
                 keys_dev,
             )
-            self._lane_tick("drp", t0_ns)
         if park:
             # no host read: bookkeeping runs now (the chunk plan is final),
             # the split keys chain on device, and the packed pull parks
@@ -1894,7 +1925,10 @@ class ContinuousBatcher(_MultiLaneMixin):
         boundary (DESIGN.md §13). A legacy 4-output step fn (tests inject
         them) degrades async to the synchronous commit."""
         t0_ns = time.perf_counter_ns()
-        out = self._step(
+        out = self._issue(
+            self._decode_lane,
+            t0_ns,
+            self._step,
             self._cache,
             self._mirror.get("tok", self._tok),
             self._mirror.get("pos", self._pos),
@@ -1903,7 +1937,6 @@ class ContinuousBatcher(_MultiLaneMixin):
             self._mirror.get("greedy", self._greedy),
             self._mirror.get("keys", self._keys),
         )
-        self._lane_tick(self._decode_lane, t0_ns)
         nxt, self._cache, pos, keys = out[:4]
         self.stats.decode_steps += 1
         self.stats.note_lane(self._decode_lane)
@@ -2268,9 +2301,15 @@ class PagedContinuousBatcher(_MultiLaneMixin):
     def admit(self, requests: Iterable[Request], now: float = 0.0) -> list:
         """Seat requests in free slots; returns the requests deferred for
         lack of pages (callers re-queue them — admission never rejects)."""
+        requests = list(requests)
+        with self._span("serve.batcher.admit") as sp:
+            deferred = self._admit(requests, now)
+            sp.note(requests=len(requests), deferred=len(deferred))
+        return deferred
+
+    def _admit(self, requests: list, now: float) -> list:
         from repro.runtime.kvcache import BlockTable
 
-        requests = list(requests)
         if requests and self._inflight:
             # admission edits the full per-slot state and re-uploads it; the
             # in-flight step must land first so those arrays are current
@@ -2313,18 +2352,23 @@ class PagedContinuousBatcher(_MultiLaneMixin):
             # is re-fed to prime generation, and keeping its page private
             # makes prompt-path writes COW-free (shared pages stay read-only
             # by construction).
-            pages, matched = self.prefix.match(prompt, shard)
-            usable = min(len(pages), (len(prompt) - 1) // self.pool.page_size)
-            for pid in pages[usable:]:
-                self.pool.decref(pid)
-            pages = pages[:usable]
-            matched = usable * self.pool.page_size
+            with self._span("serve.cache.match") as sp:
+                pages, matched = self.prefix.match(prompt, shard)
+                usable = min(
+                    len(pages), (len(prompt) - 1) // self.pool.page_size
+                )
+                for pid in pages[usable:]:
+                    self.pool.decref(pid)
+                pages = pages[:usable]
+                matched = usable * self.pool.page_size
+                sp.note(pages=usable)
             table = BlockTable(pool=self.pool, pages=pages,
                                num_tokens=matched, shard=shard)
             # first private page: the one the re-fed prompt token writes into
-            starved = not self._reclaim_pages(1, req.priority, shard) or (
-                not table.ensure_capacity(matched)
-            )
+            with self._span("serve.cache.reserve", pages=1):
+                starved = not self._reclaim_pages(1, req.priority, shard) or (
+                    not table.ensure_capacity(matched)
+                )
             # PREFILL when more than the re-fed last token remains to ingest
             # and a chunked lane exists; otherwise straight to DECODE
             # (token-by-token forcing handles any prompt remainder there).
@@ -2380,7 +2424,8 @@ class PagedContinuousBatcher(_MultiLaneMixin):
         if seated:
             # double-buffered uploads (DESIGN.md §13): stage the edited
             # arrays on the admission cold path, not the next issue
-            self._preload_step_inputs()
+            with self._span("serve.dispatch.upload", lane="preload"):
+                self._preload_step_inputs()
         return deferred
 
     def _page_upkeep(self, k: int = 0) -> None:
@@ -2390,31 +2435,38 @@ class PagedContinuousBatcher(_MultiLaneMixin):
         verify window of ``len`` (DESIGN.md §11). Growth/COW happens here,
         never in-loop. Prefilling slots are skipped — the prefill lane
         reserves its own chunk's pages before each chunk step."""
-        ps = self.pool.page_size
-        for s, req in enumerate(self._slots):
-            if req is None or not self._active[s] or self._prefilling[s]:
-                continue
-            table = self._tables[s]
-            pos = int(self._pos[s])
-            top = pos + max(self._verify_len(s, k) - 1, 0) if k > 0 else pos
-            need = table.page_index(top) + 1 - table.num_pages
-            if need > 0:
-                self._tables_changed()
-                if not self._reclaim_pages(need, req.priority, table.shard) or (
-                    not table.ensure_capacity(top)
-                ):
-                    # can't grow: preempt the requester itself
-                    self._preempt_slot(s)
+        with self._span("serve.cache.upkeep") as sp:
+            st = self.pool.stats
+            allocs0, cows0 = st.allocs, st.cow_copies
+            ps = self.pool.page_size
+            for s, req in enumerate(self._slots):
+                if req is None or not self._active[s] or self._prefilling[s]:
                     continue
-            ok = True
-            for pi in range(table.page_index(pos), table.page_index(top) + 1):
-                if not table.ensure_writable(
-                    max(pos, pi * ps), self._device_copy_page
-                ):
-                    ok = False
-                    break
-            if not ok:
-                self._preempt_slot(s)
+                table = self._tables[s]
+                pos = int(self._pos[s])
+                top = pos
+                if k > 0:
+                    top += max(self._verify_len(s, k) - 1, 0)
+                need = table.page_index(top) + 1 - table.num_pages
+                if need > 0:
+                    self._tables_changed()
+                    if not self._reclaim_pages(
+                        need, req.priority, table.shard
+                    ) or not table.ensure_capacity(top):
+                        # can't grow: preempt the requester itself
+                        self._preempt_slot(s)
+                        continue
+                ok = True
+                first, last = table.page_index(pos), table.page_index(top)
+                for pi in range(first, last + 1):
+                    if not table.ensure_writable(
+                        max(pos, pi * ps), self._device_copy_page
+                    ):
+                        ok = False
+                        break
+                if not ok:
+                    self._preempt_slot(s)
+            sp.note(allocs=st.allocs - allocs0, cow=st.cow_copies - cows0)
 
     def _device_copy_page(self, src: int, dst: int) -> None:
         self._tables_changed()  # COW swapped a page id in some table
@@ -2464,10 +2516,11 @@ class PagedContinuousBatcher(_MultiLaneMixin):
                 return False
             pages.append(pid)
         if pages:
-            self._pf_cache = self._transport(
-                self._cache, self._pf_cache, table.pages[:n], pages,
-                to_prefill=True,
-            )
+            with self._span("serve.cache.migrate", pages=n):
+                self._pf_cache = self._transport(
+                    self._cache, self._pf_cache, table.pages[:n], pages,
+                    to_prefill=True,
+                )
         self._pf_tables[s] = BlockTable(
             pool=self.pf_pool, pages=pages, num_tokens=table.num_tokens,
             shard=shard,
@@ -2495,27 +2548,28 @@ class PagedContinuousBatcher(_MultiLaneMixin):
         base = self._tables[s]
         req = self._slots[s]
         fresh = pf_t.pages[keep:]
-        if fresh:
-            shard = base.shard
-            if not self._reclaim_pages(len(fresh), req.priority, shard):
-                pf_t.release()
-                self._preempt_slot(s)
-                return False
-            mapping = migrate_pages(self.pf_pool, self.pool, fresh, shard)
-            dst = [mapping[p] for p in fresh]
-            self._cache = self._transport(
-                self._pf_cache, self._cache, fresh, dst
-            )
-            base.trim(keep)  # stale twin of the boundary page, if any
-            base.pages.extend(dst)  # import carried the refcounts over
-            self.stats.migrations += 1
-            self.stats.migrated_pages += len(fresh)
-            # the exported ids already left the prefill pool: drop them
-            # without decref so release() only returns the keep shadows
-            del pf_t.pages[keep:]
-        base.num_tokens = int(self._cursor[s])
-        pf_t.release()
-        self._tables_changed()
+        with self._span("serve.cache.migrate", pages=len(fresh)):
+            if fresh:
+                shard = base.shard
+                if not self._reclaim_pages(len(fresh), req.priority, shard):
+                    pf_t.release()
+                    self._preempt_slot(s)
+                    return False
+                mapping = migrate_pages(self.pf_pool, self.pool, fresh, shard)
+                dst = [mapping[p] for p in fresh]
+                self._cache = self._transport(
+                    self._pf_cache, self._cache, fresh, dst
+                )
+                base.trim(keep)  # stale twin of the boundary page, if any
+                base.pages.extend(dst)  # import carried the refcounts over
+                self.stats.migrations += 1
+                self.stats.migrated_pages += len(fresh)
+                # the exported ids already left the prefill pool: drop them
+                # without decref so release() only returns the keep shadows
+                del pf_t.pages[keep:]
+            base.num_tokens = int(self._cursor[s])
+            pf_t.release()
+            self._tables_changed()
         return True
 
     def _drop_pf_state(self, s: int) -> None:
@@ -2594,56 +2648,30 @@ class PagedContinuousBatcher(_MultiLaneMixin):
         plan = self._plan_chunks(budget)
         if not plan:
             return []
-        # ---- reserve every planned chunk's pages before the batched call.
-        # _reclaim_pages may preempt *other* slots — including ones planned
-        # earlier in this loop — so re-validate the survivors afterwards.
-        for s, cursor, chunk in plan:
-            req = self._slots[s]
-            if req is None or not self._active[s] or not self._prefilling[s]:
-                continue  # a victim of an earlier reservation's preemption
-            table = self._pf_tables[s] if self.disagg else self._tables[s]
-            need = table.page_index(cursor + chunk - 1) + 1 - table.num_pages
-            if need <= 0:
-                continue
-            if self.disagg:
-                # prefill-slice growth draws on the prefill pool alone
-                # (§17): it has no trie to evict and no decode tenants to
-                # preempt, so a dry pool preempts the requester itself
-                if not table.ensure_capacity(cursor + chunk - 1):
-                    self._preempt_slot(s)
-                continue
-            self._tables_changed()
-            if not self._reclaim_pages(
-                need, req.priority, table.shard
-            ) or (
-                not table.ensure_capacity(cursor + chunk - 1)
-            ):
-                self._preempt_slot(s)  # can't grow: preempt the requester
-        kept = [
-            (s, cursor, chunk)
-            for s, cursor, chunk in plan
-            if self._slots[s] is not None
-            and self._active[s]
-            and self._prefilling[s]
-        ]
+        kept = self._reserve_chunks(plan)
         if not kept:
             return []
-        bucket = bucket_pow2(
-            max(c for _, _, c in kept), CHUNK_BUCKET_MIN, self.prefill_chunk
-        )
-        self._note_chunk_bucket(bucket)
-        step = self._prefill_dispatch(bucket)  # cold: slot-hit usually
-        tok = np.zeros((self.num_slots, bucket), np.int32)
-        length = np.zeros(self.num_slots, np.int32)
-        # idle rows pad with each slot's own shard-null page so padded
-        # writes stay shard-local under a data-parallel mesh (§16)
-        bt = np.repeat(self._null_fill[:, None], self.max_pages_per_req, 1)
-        for s, cursor, chunk in kept:
-            prompt = self._slots[s].effective_prompt
-            tok[s, :chunk] = prompt[cursor : cursor + chunk]
-            length[s] = chunk
-            table = self._pf_tables[s] if self.disagg else self._tables[s]
-            bt[s, : table.num_pages] = table.pages
+        with self._span("serve.batcher.pack", lane=self._prefill_lane) as sp:
+            bucket = bucket_pow2(
+                max(c for _, _, c in kept), CHUNK_BUCKET_MIN,
+                self.prefill_chunk,
+            )
+            self._note_chunk_bucket(bucket)
+            step = self._prefill_dispatch(bucket)  # cold: slot-hit usually
+            tok = np.zeros((self.num_slots, bucket), np.int32)
+            length = np.zeros(self.num_slots, np.int32)
+            # idle rows pad with each slot's own shard-null page so padded
+            # writes stay shard-local under a data-parallel mesh (§16)
+            bt = np.repeat(
+                self._null_fill[:, None], self.max_pages_per_req, 1
+            )
+            for s, cursor, chunk in kept:
+                prompt = self._slots[s].effective_prompt
+                tok[s, :chunk] = prompt[cursor : cursor + chunk]
+                length[s] = chunk
+                table = self._pf_tables[s] if self.disagg else self._tables[s]
+                bt[s, : table.num_pages] = table.pages
+            sp.note(bucket=bucket)
         # a chunk that cannot flip any slot this step leaves every plan
         # input untouched: under async it issues and parks like a chainable
         # decode (DESIGN.md §13) — its keys must then chain through the
@@ -2664,48 +2692,44 @@ class PagedContinuousBatcher(_MultiLaneMixin):
         # idle rows carry length 0 + null tables (writes hit the null page)
         self.stats.prefill_calls += 1
         self.stats.note_lane(self._prefill_lane)
-        if self.disagg:
-            # chunk-plan inputs go host->prefill-slice in ONE hop (the
-            # mirror's device arrays are committed to the decode slice, and
-            # a plain upload would land on the default device and bounce);
-            # slow-moving per-slot sampling state is staged on the slice
-            # and re-uploaded only when its host value changes (§17)
-            self.stats.h2d_uploads += 5
-            tok_dev, start_dev, length_dev, bt_dev, keys_dev = self._pf_put(
-                (tok, np.array(self._pos, np.int32), length, bt, self._keys)
-            )
-            temps_dev = self._pf_stage("temps", self._temps)
-            greedy_dev = self._pf_stage("greedy", self._greedy)
-        else:
-            self.stats.h2d_uploads += 4 if park else 5
-            tok_dev = jnp.asarray(tok)
-            start_dev = jnp.asarray(np.array(self._pos, np.int32))
-            length_dev = jnp.asarray(length)
-            bt_dev = jnp.asarray(bt)
-            temps_dev = self._mirror.get("temps", self._temps)
-            greedy_dev = self._mirror.get("greedy", self._greedy)
-            keys_dev = (
-                self._mirror.get("keys", self._keys)
-                if park
-                else jnp.asarray(self._keys)
-            )
+        with self._span("serve.dispatch.upload", lane=self._prefill_lane):
+            if self.disagg:
+                # chunk-plan inputs go host->prefill-slice in ONE hop (the
+                # mirror's device arrays are committed to the decode slice,
+                # and a plain upload would land on the default device and
+                # bounce); slow-moving per-slot sampling state is staged on
+                # the slice and re-uploaded only when its host value
+                # changes (§17)
+                self.stats.h2d_uploads += 5
+                tok_dev, start_dev, length_dev, bt_dev, keys_dev = (
+                    self._pf_put((tok, np.array(self._pos, np.int32),
+                                  length, bt, self._keys))
+                )
+                temps_dev = self._pf_stage("temps", self._temps)
+                greedy_dev = self._pf_stage("greedy", self._greedy)
+            else:
+                self.stats.h2d_uploads += 4 if park else 5
+                tok_dev = jnp.asarray(tok)
+                start_dev = jnp.asarray(np.array(self._pos, np.int32))
+                length_dev = jnp.asarray(length)
+                bt_dev = jnp.asarray(bt)
+                temps_dev = self._mirror.get("temps", self._temps)
+                greedy_dev = self._mirror.get("greedy", self._greedy)
+                keys_dev = (
+                    self._mirror.get("keys", self._keys)
+                    if park
+                    else jnp.asarray(self._keys)
+                )
         cache_in = self._pf_cache if self.disagg else self._cache
         t0_ns = time.perf_counter_ns()
-        nxt, cache_out, new_keys = step(
-            cache_in,
-            tok_dev,
-            start_dev,
-            bt_dev,
-            length_dev,
-            temps_dev,
-            greedy_dev,
-            keys_dev,
+        nxt, cache_out, new_keys = self._issue(
+            self._prefill_lane, t0_ns, step, cache_in, tok_dev, start_dev,
+            bt_dev, length_dev, temps_dev, greedy_dev, keys_dev,
         )
         if self.disagg:
             self._pf_cache = cache_out
         else:
             self._cache = cache_out
-        self._lane_tick(self._prefill_lane, t0_ns)
         # draft mirror (DESIGN.md §11): the draft stack ingests the same
         # chunk windows into its dense per-slot cache so its KV tracks the
         # committed stream before the draft lane runs; the inputs are the
@@ -2718,34 +2742,91 @@ class PagedContinuousBatcher(_MultiLaneMixin):
             dstep = self._draft_prefill_dispatch(bucket)
             self.stats.note_lane("drp")
             t0_ns = time.perf_counter_ns()
-            _, self._draft_cache, _ = dstep(
-                self._draft_cache,
-                tok_dev,
-                start_dev,
-                length_dev,
-                self._mirror.get("temps", self._temps),
-                self._mirror.get("greedy", self._greedy),
-                keys_dev,
+            _, self._draft_cache, _ = self._issue(
+                "drp", t0_ns, dstep, self._draft_cache, tok_dev, start_dev,
+                length_dev, temps_dev, greedy_dev, keys_dev,
             )
-            self._lane_tick("drp", t0_ns)
+        with self._span("serve.dispatch.issue", lane="pack"):
+            packed = pack_step_d2h(nxt, new_keys)
         if park:
             # no host read: bookkeeping runs now (the chunk plan is final),
             # the split keys chain on device, and the packed pull parks
             # until the pipeline's next emit boundary
-            self._mirror.put("keys", new_keys)
-            for s, cursor, chunk in kept:
-                self._chunk_slots.add(s)
-                cursor += chunk
-                self._cursor[s] = cursor
-                self._pos[s] = cursor
-                self._tables[s].num_tokens = cursor
-                self.stats.prompt_tokens += chunk
-                self.stats.prefill_chunks += 1
-            self._mirror.touch("pos")
-            self._queue_prefill(pack_step_d2h(nxt, new_keys))
+            with self._span("serve.batcher.commit", flips=0):
+                self._mirror.put("keys", new_keys)
+                for s, cursor, chunk in kept:
+                    self._chunk_slots.add(s)
+                    cursor += chunk
+                    self._cursor[s] = cursor
+                    self._pos[s] = cursor
+                    self._tables[s].num_tokens = cursor
+                    self.stats.prompt_tokens += chunk
+                    self.stats.prefill_chunks += 1
+                self._mirror.touch("pos")
+                self._queue_prefill(packed)
             return []
         # one packed transfer for the chunk's host-bound outputs (§13)
-        p = self._pull(pack_step_d2h(nxt, new_keys))
+        p = self._pull(packed)
+        with self._span("serve.batcher.commit") as sp:
+            finished = self._commit_chunks(kept, p, now)
+            sp.note(flips=len(self._flip_slots))
+        return finished
+
+    def _reserve_chunks(self, plan: list) -> list:
+        """Reserve every planned chunk's pages before the batched call
+        (reclaim -> preempt-self on OOM); returns the planned chunks whose
+        slots survived. ``_reclaim_pages`` may preempt *other* slots —
+        including ones planned earlier in this loop — so the survivors are
+        re-validated afterwards."""
+        with self._span("serve.cache.reserve") as sp:
+            ev0 = self.pool.stats.prefix_evictions
+            pre0 = self.stats.preemptions
+            needed = 0
+            for s, cursor, chunk in plan:
+                req = self._slots[s]
+                if (
+                    req is None
+                    or not self._active[s]
+                    or not self._prefilling[s]
+                ):
+                    continue  # a victim of an earlier reservation's preemption
+                table = self._pf_tables[s] if self.disagg else self._tables[s]
+                need = (
+                    table.page_index(cursor + chunk - 1) + 1 - table.num_pages
+                )
+                if need <= 0:
+                    continue
+                needed += need
+                if self.disagg:
+                    # prefill-slice growth draws on the prefill pool alone
+                    # (§17): it has no trie to evict and no decode tenants
+                    # to preempt, so a dry pool preempts the requester
+                    if not table.ensure_capacity(cursor + chunk - 1):
+                        self._preempt_slot(s)
+                    continue
+                self._tables_changed()
+                if not self._reclaim_pages(
+                    need, req.priority, table.shard
+                ) or not table.ensure_capacity(cursor + chunk - 1):
+                    self._preempt_slot(s)  # can't grow: preempt the requester
+            kept = [
+                (s, cursor, chunk)
+                for s, cursor, chunk in plan
+                if self._slots[s] is not None
+                and self._active[s]
+                and self._prefilling[s]
+            ]
+            sp.note(
+                pages=needed,
+                evicted=self.pool.stats.prefix_evictions - ev0,
+                preempted=self.stats.preemptions - pre0,
+            )
+        return kept
+
+    def _commit_chunks(self, kept: list, p, now: float) -> list[Request]:
+        """Prefill bookkeeping after the chunk call's pull: cursors, split
+        keys, and at each flip the migration (split topology), the prefix
+        publish of the prompt's full pages and the first token."""
         nxt_host = p[:, 0]
         nk = p[:, 1:3].astype(np.uint32)
         finished: list[Request] = []
@@ -2774,9 +2855,7 @@ class PagedContinuousBatcher(_MultiLaneMixin):
                 # next step on
                 self._tables_changed()
                 # publish the prompt's full pages for sharing at the flip
-                full = len(prompt) // self.pool.page_size
-                if full > 0:
-                    self.prefix.insert(prompt, table.pages[:full])
+                self._publish(prompt, table)
                 self._prompt_cached[s] = True
                 self._prime_first_token(s, req, int(nxt_host[s]), now)
                 if req.done:  # new_tokens == 1: the primed token was last
@@ -2791,6 +2870,13 @@ class PagedContinuousBatcher(_MultiLaneMixin):
                     finished.append(req)
         self._mirror.touch("pos", "keys")
         return finished
+
+    def _publish(self, prompt, table) -> None:
+        """Insert a written prompt's full pages into the prefix cache."""
+        full = len(prompt) // self.pool.page_size
+        if full > 0:
+            with self._span("serve.cache.publish", pages=full):
+                self.prefix.insert(prompt, table.pages[:full])
 
     # -------------------------------------------------------------- hot path
     def _step_impl(self, now: float = 0.0) -> list[Request]:
@@ -2836,41 +2922,46 @@ class PagedContinuousBatcher(_MultiLaneMixin):
         """Paged decode lane: capacity-bucket dispatch, packed block
         tables, one direct executable call. Synchronous mode pulls and
         emits immediately; async parks the step (DESIGN.md §13)."""
-        bucket = bucket_pow2(
-            max(
-                [t.num_pages for s, t in enumerate(self._tables)
-                 if t is not None and decoding[s]] or [1]
-            ) or 1,
-            1,
-            self.max_pages_per_req,
-        )
-        if bucket != self._pages_bucket:
-            self.stats.bucket_crossings += 1
-            self._pages_bucket = bucket
-            self._tables_changed()  # table width changed
-        step = self._dispatch(bucket)  # cold: slot-hit unless bucket moved
-        if self._bt_dirty:
-            # pad with each slot's shard-null page (all zeros on a
-            # single-shard pool — the historical NULL_PAGE fill)
-            bt = np.repeat(self._null_fill[:, None], bucket, 1)
-            for s, table in enumerate(self._tables):
-                if table is not None and decoding[s]:
-                    bt[s, : table.num_pages] = table.pages
-            self._bt_host = bt
-            self._bt_dirty = False
-            self._mirror.touch("bt")
+        with self._span("serve.batcher.pack", lane=self._decode_lane) as sp:
+            bucket = bucket_pow2(
+                max(
+                    [t.num_pages for s, t in enumerate(self._tables)
+                     if t is not None and decoding[s]] or [1]
+                ) or 1,
+                1,
+                self.max_pages_per_req,
+            )
+            if bucket != self._pages_bucket:
+                self.stats.bucket_crossings += 1
+                self._pages_bucket = bucket
+                self._tables_changed()  # table width changed
+            step = self._dispatch(bucket)  # cold: slot-hit unless it moved
+            if self._bt_dirty:
+                # pad with each slot's shard-null page (all zeros on a
+                # single-shard pool — the historical NULL_PAGE fill)
+                bt = np.repeat(self._null_fill[:, None], bucket, 1)
+                for s, table in enumerate(self._tables):
+                    if table is not None and decoding[s]:
+                        bt[s, : table.num_pages] = table.pages
+                self._bt_host = bt
+                self._bt_dirty = False
+                self._mirror.touch("bt")
+            sp.note(bucket=bucket)
         t0_ns = time.perf_counter_ns()
-        out = step(
-            self._cache,
-            self._mirror.get("tok", self._tok),
-            self._mirror.get("pos", self._pos),
-            self._mirror.get("bt", self._bt_host),
-            self._mirror.get("active", decoding),
-            self._mirror.get("temps", self._temps),
-            self._mirror.get("greedy", self._greedy),
-            self._mirror.get("keys", self._keys),
-        )
-        self._lane_tick(self._decode_lane, t0_ns)
+        with self._span("serve.dispatch.upload", lane=self._decode_lane) as sp:
+            m = self._mirror
+            u0 = self.stats.h2d_uploads
+            args = (
+                m.get("tok", self._tok),
+                m.get("pos", self._pos),
+                m.get("bt", self._bt_host),
+                m.get("active", decoding),
+                m.get("temps", self._temps),
+                m.get("greedy", self._greedy),
+                m.get("keys", self._keys),
+            )
+            sp.note(uploads=self.stats.h2d_uploads - u0)
+        out = self._issue(self._decode_lane, t0_ns, step, self._cache, *args)
         nxt, self._cache, pos, keys = out[:4]
         self.stats.decode_steps += 1
         self.stats.note_lane(self._decode_lane)
@@ -2894,6 +2985,15 @@ class PagedContinuousBatcher(_MultiLaneMixin):
         committing step's position frontier — under run-ahead the live
         ``self._pos`` is already one step further, so tables sync to the
         record's own positions, never the live array."""
+        with self._span("serve.batcher.emit") as sp:
+            t0 = self.stats.tokens
+            finished = self._emit_tokens(nxt_host, pos_host, now)
+            sp.note(tokens=self.stats.tokens - t0, finished=len(finished))
+        return finished
+
+    def _emit_tokens(
+        self, nxt_host, pos_host, now: float
+    ) -> list[Request]:
         finished: list[Request] = []
         if self._faults is not None:
             nxt_host, _ = self._inject_step_output(nxt_host)
@@ -2919,9 +3019,7 @@ class PagedContinuousBatcher(_MultiLaneMixin):
                 continue
             if not self._prompt_cached[s]:
                 # prompt fully written: publish its full pages for sharing
-                full = len(prompt) // self.pool.page_size
-                if full > 0:
-                    self.prefix.insert(prompt, table.pages[:full])
+                self._publish(prompt, table)
                 self._prompt_cached[s] = True
             if int(nxt_host[s]) < 0:
                 # NaN guard (§15): invalid token id — quarantine this slot
@@ -2981,10 +3079,7 @@ class PagedContinuousBatcher(_MultiLaneMixin):
         """Prompt fully written: publish its full pages for sharing (the
         verify-lane twin of the decode lane's flip-less publication)."""
         if not self._prompt_cached[s]:
-            prompt = req.effective_prompt
-            full = len(prompt) // self.pool.page_size
-            if full > 0:
-                self.prefix.insert(prompt, self._tables[s].pages[:full])
+            self._publish(req.effective_prompt, self._tables[s])
             self._prompt_cached[s] = True
 
     def _after_commit(self, s: int, req: Request) -> None:

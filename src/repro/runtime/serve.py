@@ -1321,12 +1321,11 @@ class Engine:
         # Burst/continuous report parity (DESIGN.md §14): burst steps feed
         # the same registry families the batcher lanes do, under the
         # "burst" lane label. Handles are cached; the loop pays one counter
-        # add, one histogram bisect, and an is-None check per step.
+        # add, one histogram bisect, and a phase span's check per step.
         if self._burst_calls is None:
             reg = self.telemetry.registry
             self._burst_calls = reg.counter("lane_calls_total", lane="burst")
             self._burst_hist = reg.histogram("lane_step_ms", lane="burst")
-        rec = self.telemetry.trace_or_none()
         out = []
         pos = start_pos
         for i in range(num_tokens):
@@ -1334,17 +1333,15 @@ class Engine:
             # need the singleton seq axis the model expects ([B,1,D]).
             tok2d = tok if self.cfg.input_kind == "tokens" else tok[:, None, :]
             t0_ns = time.perf_counter_ns()
-            tok, cache = exe(
-                self.params, cache, tok2d, jnp.int32(pos), step_keys[i]
-            )
+            with self.telemetry.span(
+                "serve.dispatch.issue", lane="burst", step=i
+            ):
+                tok, cache = exe(
+                    self.params, cache, tok2d, jnp.int32(pos), step_keys[i]
+                )
             dt_ns = time.perf_counter_ns() - t0_ns
             self._burst_calls.inc()
             self._burst_hist.observe(dt_ns / 1e6)
-            if rec is not None:
-                rec.emit(
-                    "lane_step", "lane:burst", ph="X",
-                    ts_ns=t0_ns, dur_ns=dt_ns, args={"step": i},
-                )
             out.append(tok)
             if on_step is not None:
                 on_step(i, tok)

@@ -18,6 +18,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import models, perf
 from repro.configs import ArchConfig, ShapeSpec
+from repro.core.telemetry import span
 from repro.distributed import sharding as shd
 from repro.models.model import input_specs
 from repro.optim import adamw
@@ -275,18 +276,15 @@ def pull_host(dev, recorder=None) -> tuple[np.ndarray, int]:
 
     This is *the* sync point of the step pipeline (DESIGN.md §13) — on the
     async path the only place the host ever blocks on the device — so it is
-    also where the flight recorder measures device wait. Returns
-    ``(host_array, elapsed_ns)``; when ``recorder`` is an enabled
-    ``FlightRecorder`` a "d2h" span lands on the scheduler track with the
-    transfer size."""
-    t0 = time.perf_counter_ns()
-    out = np.asarray(dev)
-    dt = time.perf_counter_ns() - t0
-    if recorder is not None and recorder.enabled:
-        recorder.emit(
-            "d2h", "scheduler", ph="X", ts_ns=t0, dur_ns=dt,
-            args={"nbytes": int(out.nbytes), "shape": list(out.shape)},
-        )
+    also where device wait is measured. Returns ``(host_array,
+    elapsed_ns)``. The pull is the ``serve.dispatch.pull`` phase span
+    (``core.telemetry.span``, with the transfer's bytes): on the profiler's
+    trace while it collects, on ``recorder`` while that is enabled."""
+    with span("serve.dispatch.pull", recorder) as sp:
+        t0 = time.perf_counter_ns()
+        out = np.asarray(dev)
+        dt = time.perf_counter_ns() - t0
+        sp.note(bytes=int(out.nbytes))
     return out, dt
 
 

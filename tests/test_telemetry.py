@@ -17,10 +17,12 @@ from repro.configs import get_config
 from repro.core import reset_entry_points
 from repro.core.telemetry import (
     DEFAULT_MS_BUCKETS,
+    NO_SPAN,
     FlightRecorder,
     Histogram,
     MetricsRegistry,
     Telemetry,
+    span,
 )
 from repro.runtime.scheduler import Request, latency_report
 from repro.runtime.serve import (
@@ -243,7 +245,8 @@ def test_continuous_report_derives_from_registry(smoke_setup):
 
     # the flight recorder saw the full taxonomy on the dense stack
     names = {e.name for e in tel.recorder.events()}
-    assert {"compile", "lane_step", "admit", "finish", "d2h",
+    assert {"compile", "serve.dispatch.issue", "admit", "finish",
+            "serve.dispatch.pull", "serve.batcher.step",
             "warm_boundary"} <= names
 
     # second stream on the same engine: the boundary rolls again, so the
@@ -314,11 +317,29 @@ def test_pull_host_emits_d2h_span():
     out, dt_ns = pull_host(np.arange(6, dtype=np.int32).reshape(2, 3), rec)
     assert out.shape == (2, 3) and dt_ns >= 0
     (ev,) = rec.events()
-    assert ev.name == "d2h" and ev.ph == "X" and ev.track == "scheduler"
-    assert ev.args["nbytes"] == out.nbytes and ev.args["shape"] == [2, 3]
+    assert ev.name == "serve.dispatch.pull" and ev.ph == "X"
+    assert ev.track == "scheduler" and ev.dur_ns >= dt_ns
+    assert ev.args == {"bytes": out.nbytes}
     # disabled recorder: same result, no events
     out2, _ = pull_host(np.zeros(3), None)
     assert out2.shape == (3,)
+
+
+def test_phase_span_lands_on_the_recorder_with_its_notes():
+    rec = FlightRecorder(capacity=8, enabled=True)
+    with span("serve.batcher.emit", rec, step=3) as sp:
+        sp.note(tokens=2, finished=0)
+    (ev,) = rec.events()
+    assert ev.name == "serve.batcher.emit" and ev.ph == "X"
+    assert ev.track == "scheduler" and ev.dur_ns >= 0
+    assert ev.args == {"step": 3, "tokens": 2, "finished": 0}
+    # with the recorder off and no profiler collecting: the shared no-op
+    rec.enabled = False
+    sp = span("serve.batcher.emit", rec, step=4)
+    assert sp is NO_SPAN and span("serve.batcher.emit") is NO_SPAN
+    with sp as inner:
+        inner.note(tokens=1)
+    assert rec.emitted == 1
 
 
 def test_page_pool_events():
