@@ -12,7 +12,9 @@ This module makes the key space first-class (DESIGN.md §12):
 * ``LaneAxis``    — one coordinate of a lane's key: a name plus the *bucket
                     ladder* that enumerates its warmup fan-out (an engine
                     method name, e.g. ``"_chunk_buckets"``), or ``None``
-                    for axes the caller pins per batcher (``slots``).
+                    for axes the caller pins per batcher (``slots``);
+                    ``per`` sizes a ladder by a pinned axis (``rows``
+                    below ``slots``).
 * ``LaneSpec``    — one lane's declaration: name, role (stats grouping),
                     ordered axes, and the engine hook names that build
                     (``builder``), dummy-run (``warmer``), and gate
@@ -89,14 +91,24 @@ class LaneAxis:
 
     name: str
     ladder: str | None = None
+    # The pinned axis this ladder is sized by: the ladder method receives
+    # that axis's value (the row buckets below a batcher's ``slots``).
+    per: str | None = None
 
-    def values(self, engine: Any) -> tuple:
+    def values(self, engine: Any, pinned: dict | None = None) -> tuple:
         if self.ladder is None:
             raise UnknownLaneError(
                 f"axis {self.name!r} has no ladder; pin it via fanout(..., "
                 f"{self.name}=value)"
             )
-        return tuple(getattr(engine, self.ladder)())
+        if self.per is None:
+            return tuple(getattr(engine, self.ladder)())
+        if not pinned or self.per not in pinned:
+            raise UnknownLaneError(
+                f"axis {self.name!r} is sized per {self.per!r}; pin it via "
+                f"fanout(..., {self.per}=value)"
+            )
+        return tuple(getattr(engine, self.ladder)(pinned[self.per]))
 
 
 @dataclass(frozen=True)
@@ -170,7 +182,7 @@ class LaneSpec:
                 f"(axes: {self.axis_names})"
             )
         ranges = [
-            ((pinned[a.name],) if a.name in pinned else a.values(engine))
+            ((pinned[a.name],) if a.name in pinned else a.values(engine, pinned))
             for a in self.axes
         ]
         return [self.key(*combo) for combo in itertools.product(*ranges)]
@@ -242,6 +254,9 @@ LANES = LaneRegistry()
 _SLOTS = LaneAxis("slots")  # pinned per batcher (continuous(slots=...))
 _PAGES = LaneAxis("pages_bucket", "_pages_buckets")
 _CHUNK = LaneAxis("chunk_bucket", "_chunk_buckets")
+# The row buckets {1, 2, 4, ...} below the batcher's slot count: how many
+# rows a row-bucketed prefill call carries (DESIGN.md §10/§12).
+_ROWS = LaneAxis("rows", "_row_buckets", per="slots")
 _KBUCKET = LaneAxis("k_bucket", "_k_buckets")
 _KVDTYPE = LaneAxis("kv_dtype", "_warm_kv_dtypes")
 # Draft lanes carry their own storage-dtype ladder: an int8 draft cache can
@@ -290,6 +305,18 @@ PF = LANES.register(LaneSpec(
     slice="prefill",
     doc="Paged chunked prefill, batched: every prefilling slot the budget "
         "covers rides one call (DESIGN.md §10/§12).",
+))
+
+PFR = LANES.register(LaneSpec(
+    name="pfr", role="prefill",
+    axes=(_SLOTS, _ROWS, _KVDTYPE, _MESH),
+    builder="_build_paged_prefill_rows", warmer="_warm_pfr",
+    engines=frozenset({"paged"}), enabled="_row_prefill_enabled",
+    slice="prefill",
+    doc="Paged chunked prefill sized by the slots that prefill: the "
+        "chunks packed into a row bucket below the slot count, at the top "
+        "chunk bucket; per-slot inputs and outputs stay [slots] "
+        "(DESIGN.md §10/§12).",
 ))
 
 PFD = LANES.register(LaneSpec(

@@ -433,6 +433,11 @@ class BatcherStats:
     prompt_tokens: int = 0  # teacher-forced (not emitted) tokens
     prefill_chunks: int = 0  # chunks ingested (rows; batched calls carry >1)
     prefill_calls: int = 0  # prefill-lane executable calls
+    # Rows of those calls (DESIGN.md §10): the chunks they carried, and
+    # the rows they were sized at (a row bucket, or every slot); the ratio
+    # is the prefill call's row fill.
+    prefill_rows: int = 0
+    prefill_rows_padded: int = 0
     chunk_bucket_crossings: int = 0
     h2d_uploads: int = 0  # host->device coordinate uploads (see _DeviceMirror)
     h2d_overlapped: int = 0  # uploads issued while a step was in flight
@@ -1781,6 +1786,8 @@ class ContinuousBatcher(_MultiLaneMixin):
             prompt = self._slots[s].effective_prompt
             tok[s, :chunk] = prompt[cursor : cursor + chunk]
             length[s] = chunk
+        self.stats.prefill_rows += len(plan)
+        self.stats.prefill_rows_padded += self.num_slots
         # a chunk that cannot flip any slot this step leaves every plan
         # input untouched: under async it issues and parks like a chainable
         # decode (DESIGN.md §13) — its keys must then chain through the
@@ -2074,6 +2081,7 @@ class PagedContinuousBatcher(_MultiLaneMixin):
         prefill_dispatch: Callable[[int], Callable] | None = None,
         prefill_chunk: int = 0,
         token_budget: int = 0,
+        prefill_rows_dispatch: Callable[[int], Callable] | None = None,
         draft_dispatch: Callable[[int], Callable] | None = None,
         verify_dispatch: Callable[[int], Callable] | None = None,
         draft_prefill_dispatch: Callable[[int], Callable] | None = None,
@@ -2142,6 +2150,12 @@ class PagedContinuousBatcher(_MultiLaneMixin):
         # chunked prefill (DESIGN.md §10): PREFILL/DECODE state per slot
         self._prefill_dispatch = prefill_dispatch
         self.prefill_chunk = prefill_chunk if prefill_dispatch else 0
+        # row-bucketed prefill (DESIGN.md §10): rows -> the executable of
+        # the ("pfr", slots, rows, ...) key; None keeps every call at the
+        # slot count (spec lanes on, sharded pool, or no engine)
+        self._prefill_rows_dispatch = (
+            prefill_rows_dispatch if prefill_dispatch else None
+        )
         self.token_budget = token_budget or (num_slots + self.prefill_chunk)
         self._chunk_bucket = 0
         self._prefilling = np.zeros(num_slots, bool)
@@ -2637,14 +2651,16 @@ class PagedContinuousBatcher(_MultiLaneMixin):
         §10/§12): plan and flip semantics live in ``_MultiLaneMixin``; this
         body is the paged storage half. Every planned chunk's pages are
         reserved up front (reclaim -> preempt-self on OOM, exactly like
-        decode growth), then every surviving slot rides one
-        ``("pf", slots, chunk_bucket, kv_dtype, mesh)`` call — per-row chunk
-        windows through per-row block tables, length 0 = idle row, padded
-        columns writing only the null page. Rows are independent (each
-        writes its own private pages), so the batched call is bitwise-equal
-        to running the chunks one slot at a time; the flip publishes each
-        prompt's full pages to the prefix cache. This closes PR 4's open
-        item: the paged prompt path is no longer B=1 per step."""
+        decode growth), then every surviving slot rides one call — per-row
+        chunk windows through per-row block tables, length 0 = idle row,
+        padded columns writing only the null page. The call is sized by the
+        chunks it carries: when they fit a power-of-two row bucket below the
+        slot count, they pack into its rows at the top chunk bucket, one
+        ``("pfr", slots, rows, kv_dtype, mesh)`` call; otherwise row ``s``
+        is slot ``s`` in a ``("pf", slots, chunk_bucket, kv_dtype, mesh)``
+        call. Rows are independent (each writes its own private pages), so
+        the batched call is equal to running the chunks one slot at a time;
+        the flip publishes each prompt's full pages to the prefix cache."""
         plan = self._plan_chunks(budget)
         if not plan:
             return []
@@ -2652,26 +2668,43 @@ class PagedContinuousBatcher(_MultiLaneMixin):
         if not kept:
             return []
         with self._span("serve.batcher.pack", lane=self._prefill_lane) as sp:
-            bucket = bucket_pow2(
-                max(c for _, _, c in kept), CHUNK_BUCKET_MIN,
-                self.prefill_chunk,
+            n_rows = bucket_pow2(len(kept), 1, self.num_slots)
+            packed_rows = (
+                n_rows < self.num_slots
+                and self._prefill_rows_dispatch is not None
             )
+            if packed_rows:
+                # the row executables exist at the top chunk bucket only
+                bucket = self._chunk_max
+                step = self._prefill_rows_dispatch(n_rows)
+            else:
+                n_rows = self.num_slots
+                bucket = bucket_pow2(
+                    max(c for _, _, c in kept), CHUNK_BUCKET_MIN,
+                    self.prefill_chunk,
+                )
+                step = self._prefill_dispatch(bucket)  # cold: slot-hit usually
             self._note_chunk_bucket(bucket)
-            step = self._prefill_dispatch(bucket)  # cold: slot-hit usually
-            tok = np.zeros((self.num_slots, bucket), np.int32)
-            length = np.zeros(self.num_slots, np.int32)
+            tok = np.zeros((n_rows, bucket), np.int32)
+            length = np.zeros(n_rows, np.int32)
             # idle rows pad with each slot's own shard-null page so padded
-            # writes stay shard-local under a data-parallel mesh (§16)
+            # writes stay shard-local under a data-parallel mesh (§16); a
+            # packed call only runs on an unsharded pool, one null page
             bt = np.repeat(
-                self._null_fill[:, None], self.max_pages_per_req, 1
+                self._null_fill[:n_rows, None], self.max_pages_per_req, 1
             )
-            for s, cursor, chunk in kept:
+            rows = np.full(n_rows, self.num_slots, np.int32)  # pad: dropped
+            for i, (s, cursor, chunk) in enumerate(kept):
+                r = i if packed_rows else s
                 prompt = self._slots[s].effective_prompt
-                tok[s, :chunk] = prompt[cursor : cursor + chunk]
-                length[s] = chunk
+                tok[r, :chunk] = prompt[cursor : cursor + chunk]
+                length[r] = chunk
+                rows[r] = s
                 table = self._pf_tables[s] if self.disagg else self._tables[s]
-                bt[s, : table.num_pages] = table.pages
-            sp.note(bucket=bucket)
+                bt[r, : table.num_pages] = table.pages
+            self.stats.prefill_rows += len(kept)
+            self.stats.prefill_rows_padded += n_rows
+            sp.note(bucket=bucket, rows=n_rows)
         # a chunk that cannot flip any slot this step leaves every plan
         # input untouched: under async it issues and parks like a chainable
         # decode (DESIGN.md §13) — its keys must then chain through the
@@ -2700,19 +2733,21 @@ class PagedContinuousBatcher(_MultiLaneMixin):
                 # bounce); slow-moving per-slot sampling state is staged on
                 # the slice and re-uploaded only when its host value
                 # changes (§17)
-                self.stats.h2d_uploads += 5
-                tok_dev, start_dev, length_dev, bt_dev, keys_dev = (
-                    self._pf_put((tok, np.array(self._pos, np.int32),
-                                  length, bt, self._keys))
+                host = (tok, np.array(self._pos, np.int32), length, bt,
+                        self._keys) + ((rows,) if packed_rows else ())
+                self.stats.h2d_uploads += len(host)
+                tok_dev, start_dev, length_dev, bt_dev, keys_dev, *rows_dev = (
+                    self._pf_put(host)
                 )
                 temps_dev = self._pf_stage("temps", self._temps)
                 greedy_dev = self._pf_stage("greedy", self._greedy)
             else:
-                self.stats.h2d_uploads += 4 if park else 5
+                self.stats.h2d_uploads += (4 if park else 5) + packed_rows
                 tok_dev = jnp.asarray(tok)
                 start_dev = jnp.asarray(np.array(self._pos, np.int32))
                 length_dev = jnp.asarray(length)
                 bt_dev = jnp.asarray(bt)
+                rows_dev = [jnp.asarray(rows)] if packed_rows else []
                 temps_dev = self._mirror.get("temps", self._temps)
                 greedy_dev = self._mirror.get("greedy", self._greedy)
                 keys_dev = (
@@ -2724,7 +2759,7 @@ class PagedContinuousBatcher(_MultiLaneMixin):
         t0_ns = time.perf_counter_ns()
         nxt, cache_out, new_keys = self._issue(
             self._prefill_lane, t0_ns, step, cache_in, tok_dev, start_dev,
-            bt_dev, length_dev, temps_dev, greedy_dev, keys_dev,
+            bt_dev, length_dev, temps_dev, greedy_dev, keys_dev, *rows_dev,
         )
         if self.disagg:
             self._pf_cache = cache_out

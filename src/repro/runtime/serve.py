@@ -27,7 +27,8 @@ are AOT-warmed (log-sized fan-out), so bucket crossings rebind but never
 compile.
 
 Both continuous engines run a **multi-lane step pipeline** (DESIGN.md
-§10/§11): prefill chunks through ``("pf"/"pfd", ..., chunk_bucket)``, and —
+§10/§11): prefill chunks through ``("pf"/"pfd", ..., chunk_bucket)`` (or,
+when few paged slots prefill, a row bucket: ``("pfr", slots, rows, ...)``), and —
 with ``spec_k > 0`` — speculative decoding through the draft/verify lanes:
 ``("dr", slots, k_bucket)`` runs a truncated-layer *view* of the target
 (``models.draft_view``, no extra weights) K steps in one executable, and
@@ -100,7 +101,10 @@ class EngineConfig:
     # step. 0 disables the chunked lane (prompts teacher-force token by
     # token at decode speed — the baseline). Chunk sizes are drawn from the
     # log-sized bucket set {8, 16, ..., prefill_chunk}, each an AOT-warmed
-    # ("pf", slots, chunk_bucket, kv_dtype) dispatch key.
+    # ("pf", slots, chunk_bucket, kv_dtype, mesh) dispatch key. A paged
+    # call whose prefilling slots fit a smaller power-of-two row bucket
+    # runs at prefill_chunk columns as ("pfr", slots, rows, kv_dtype, mesh)
+    # instead, unless spec lanes are on or the page pool is sharded.
     prefill_chunk: int = 0
     # Per-step token budget split across the lanes by the LanePolicy;
     # 0 = slots + prefill_chunk.
@@ -435,8 +439,8 @@ class Engine:
         kv_dtype: str = "fp32",
         mesh: str = "1x1",
     ) -> Callable:
-        """Executable for the ``("pf", slots, chunk_bucket, kv_dtype)``
-        dispatch key: *batched* paged chunked prefill.
+        """Executable for the ``("pf", slots, chunk_bucket, kv_dtype,
+        mesh)`` dispatch key: *batched* paged chunked prefill.
 
         Chunk size is the headline semi-static condition (DESIGN.md §10):
         the chunk width is baked into the shapes, one executable per bucket
@@ -447,7 +451,8 @@ class Engine:
         the old B=1-per-step limitation is gone. The block-table width is
         pinned at the per-request page cap (masked positions contribute
         exactly nothing); the page dtype is the registry's ``kv_dtype``
-        axis (DESIGN.md §12).
+        axis (DESIGN.md §12). A call carrying fewer chunks than a smaller
+        row bucket holds goes to ``_build_paged_prefill_rows`` instead.
         """
         cfg, ecfg = self.cfg, self.ecfg
         step = steps_mod.make_paged_prefill_fn(cfg, moe_policy=ecfg.moe_policy)
@@ -468,6 +473,49 @@ class Engine:
         )
         return self._compile_step(
             step, mesh, self._abstract_params(), c_shape, rows, "paged"
+        )
+
+    def _build_paged_prefill_rows(
+        self,
+        slots: int,
+        rows: int,
+        kv_dtype: str = "fp32",
+        mesh: str = "1x1",
+    ) -> Callable:
+        """Executable for the ``("pfr", slots, rows, kv_dtype, mesh)``
+        dispatch key: the paged prefill sized by the slots that prefill.
+
+        The row count is a semi-static condition beside the chunk width
+        (DESIGN.md §10): the batcher packs a call's chunks into the first
+        of ``rows`` rows, a power-of-two bucket below ``slots``, and the
+        executable gathers the per-slot inputs by each row's slot and
+        scatters the outputs back, so one new prompt pays for one row, not
+        for every slot. Only the top chunk bucket is built at these row
+        counts: with the ``("pf", slots, ...)`` keys at every chunk bucket
+        the warm set is L-shaped, not a product. The per-slot arrays keep
+        the ``slots`` width, which is why ``slots`` stays in the key.
+        """
+        cfg, ecfg = self.cfg, self.ecfg
+        step = steps_mod.make_paged_prefill_fn(
+            cfg, moe_policy=ecfg.moe_policy, by_rows=True
+        )
+        c_shape = jax.eval_shape(
+            lambda: models.init_paged_cache(
+                cfg, self.pool_physical_pages, ecfg.page_size, kv_dtype
+            )
+        )
+        avals = (
+            jax.ShapeDtypeStruct((rows, ecfg.prefill_chunk), jnp.int32),
+            jax.ShapeDtypeStruct((slots,), jnp.int32),
+            jax.ShapeDtypeStruct((rows, self.max_pages_per_req), jnp.int32),
+            jax.ShapeDtypeStruct((rows,), jnp.int32),
+            jax.ShapeDtypeStruct((slots,), jnp.float32),
+            jax.ShapeDtypeStruct((slots,), jnp.bool_),
+            jax.ShapeDtypeStruct((slots, 2), jnp.uint32),
+            jax.ShapeDtypeStruct((rows,), jnp.int32),
+        )
+        return self._compile_step(
+            step, mesh, self._abstract_params(), c_shape, avals, "paged"
         )
 
     def _build_slot_prefill(
@@ -771,6 +819,15 @@ class Engine:
                 return out
             b *= 2
 
+    def _row_buckets(self, slots: int) -> list[int]:
+        """The row-bucket fan-out {1, 2, 4, ...} below ``slots``: the row
+        counts a ``("pfr", ...)`` call may carry (DESIGN.md §10)."""
+        out, r = [], 1
+        while r < slots:
+            out.append(r)
+            r *= 2
+        return out
+
     def _k_buckets(self) -> list[int]:
         """The log-sized k-bucket fan-out {1, 2, 4, ..., spec_k}."""
         if self.ecfg.spec_k <= 0:
@@ -848,6 +905,18 @@ class Engine:
         """Registry gate for the draft/verify lanes: per-batcher opt-in
         (``spec_decode=`` override) AND architectural support."""
         return bool(ctx.spec) and self._supports_spec_decode()
+
+    def _row_prefill_enabled(self, ctx: "_WarmCtx") -> bool:
+        """Registry gate for the row-bucketed prefill (DESIGN.md §10): off
+        where a prefill row has to stay on its slot's row — with spec lanes
+        on, the draft mirror reuses the call's per-slot windows against
+        its dense per-slot cache; on a sharded pool, a row's padding must
+        hit its own shard's null page."""
+        return (
+            self._supports_chunked_prefill()
+            and not self._spec_lanes_enabled(ctx)
+            and self.pool_shards == 1
+        )
 
     def _disagg_lanes_enabled(self, ctx: "_WarmCtx") -> bool:
         """Registry gate for the KV-migration lane (DESIGN.md §17): only a
@@ -942,6 +1011,22 @@ class Engine:
         # the real loop pulls through the packed-d2h helper; its jit cache
         # keys on input *placement*, so warm the pack against this mesh
         # cell's outputs too (an offset slice is a distinct variant — §17)
+        np.asarray(steps_mod.pack_step_d2h(warm[0], warm[2]))
+        ctx.paged_caches[(dt, m)] = warm[1]
+
+    def _warm_pfr(self, key: tuple, exe: Callable, ctx: _WarmCtx) -> None:
+        _, s, r, dt, m = key
+        warm = exe(
+            self.params,
+            ctx.paged_caches[(dt, m)],
+            self._warm_zeros(r, self.ecfg.prefill_chunk),
+            self._warm_zeros(s),
+            self._warm_zeros(r, self.max_pages_per_req),
+            self._warm_zeros(r),
+            *self._warm_sampling(s),
+            jnp.asarray(np.full(r, s, np.int32)),  # pad rows: all dropped
+        )
+        jax.block_until_ready(warm)
         np.asarray(steps_mod.pack_step_d2h(warm[0], warm[2]))
         ctx.paged_caches[(dt, m)] = warm[1]
 
@@ -1625,7 +1710,7 @@ class Engine:
         )
         max_pages_per_req = self.max_pages_per_req
         # Registry-driven warmup (DESIGN.md §12): every enabled paged lane
-        # (cbp, pf, vf, dr, drp), every bucket in its fan-out, every warmed
+        # (cbp, pf, pfr, vf, dr, drp), every bucket in its fan-out, every warmed
         # page dtype *and mesh* — compiled and dummy-run against a pooled
         # cache of the matching (dtype, mesh) cell. The batcher adopts the
         # active cell's cache; the rest existed only to warm executables.
@@ -1691,6 +1776,29 @@ class Engine:
                     )
 
                 return bound_prefill
+
+        prefill_rows_dispatch = None
+        if self._row_prefill_enabled(ctx) and self._row_buckets(s):
+
+            def prefill_rows_dispatch(rows: int) -> Callable:
+                # the same prefill-group routing as PF above
+                pf_mesh = mb.get("prefill", mb["mesh"])
+                pf = self._decode.dispatch(
+                    lanes_mod.PFR.key(s, rows, dt, pf_mesh)
+                )
+                pf_params = self._params_for_mesh(pf_mesh)
+
+                def bound_prefill_rows(
+                    cache, tok, start, bt, length, temps, greedy, keys,
+                    rows_of,
+                ):
+                    self.stats["hot_calls"] += 1
+                    return pf(
+                        pf_params, cache, tok, start, bt, length, temps,
+                        greedy, keys, rows_of,
+                    )
+
+                return bound_prefill_rows
 
         draft_dispatch = verify_dispatch = draft_prefill_dispatch = None
         if use_spec and self._supports_spec_decode():
@@ -1804,6 +1912,7 @@ class Engine:
             prefill_dispatch=prefill_dispatch,
             prefill_chunk=self.ecfg.prefill_chunk,
             token_budget=self.ecfg.token_budget,
+            prefill_rows_dispatch=prefill_rows_dispatch,
             draft_dispatch=draft_dispatch,
             verify_dispatch=verify_dispatch,
             draft_prefill_dispatch=draft_prefill_dispatch,

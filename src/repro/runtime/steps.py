@@ -410,7 +410,7 @@ def make_slot_verify_fn(
 
 
 def make_paged_prefill_fn(
-    cfg: ArchConfig, *, moe_policy: str = "drop"
+    cfg: ArchConfig, *, moe_policy: str = "drop", by_rows: bool = False
 ) -> Callable:
     """Chunked-prefill step through the paged KV cache (DESIGN.md §10).
 
@@ -420,9 +420,9 @@ def make_paged_prefill_fn(
 
     ``CB`` (the chunk bucket, from the log-sized set {8, 16, 32, ...}) is
     baked into the executable's shapes — the semi-static chunk key
-    ``("pf", slots, chunk_bucket, kv_dtype)``. Ingesting prompts is then a
-    handful of direct executable calls instead of one decode step per
-    token; the returned
+    ``("pf", slots, chunk_bucket, kv_dtype, mesh)``. Ingesting prompts is
+    then a handful of direct executable calls instead of one decode step
+    per token; the returned
     ``next_tok`` (sampled from the last real chunk row) primes generation
     when the chunk reaches the prompt end. Cache contents and priming
     *logits* are bit-for-bit what token-by-token forcing through
@@ -432,9 +432,24 @@ def make_paged_prefill_fn(
     not once per prompt token). Columns >= ``length`` are bucket padding:
     their K/V writes land in the reserved null page and their logits are
     never read.
+
+    ``by_rows`` makes the row count a semi-static coordinate too — the
+    ``("pfr", slots, rows, kv_dtype, mesh)`` key. The chunks ride the first
+    of R rows and ``rows[R]`` names each row's slot; everything per slot
+    stays [S]:
+
+        step(params, cache, tok[R,CB], start[S], block_tables[R,PB],
+             length[R], temps[S], greedy[S], keys[S,2], rows[R])
+          -> (next_tok[S], cache, new_keys[S,2])
+
+    The per-slot inputs are gathered by ``rows`` and the outputs scattered
+    back inside this one executable, so the contract after the call is the
+    ``[S]`` one above. Pad rows carry the out-of-range index S: they read
+    fill values, their length is 0, and the scatter drops them. Slots no
+    row names keep their keys bitwise and read 0 as their next token.
     """
 
-    def paged_prefill_step(
+    def chunk_step(
         params, cache, tok, start, block_tables, length, temps, greedy, keys
     ):
         logits, cache = models.paged_prefill_step(
@@ -448,6 +463,35 @@ def make_paged_prefill_fn(
         # copies planned rows — masking here makes both reads identical.
         new_keys = jnp.where(length[:, None] > 0, new_keys, keys)
         return nxt, cache, new_keys
+
+    if not by_rows:
+
+        def paged_prefill_step(
+            params, cache, tok, start, block_tables, length, temps, greedy,
+            keys,
+        ):
+            return chunk_step(
+                params, cache, tok, start, block_tables, length, temps,
+                greedy, keys,
+            )
+
+        return paged_prefill_step
+
+    # The jitted function keeps the name ``paged_prefill_step`` in both
+    # forms: device traces find the prefill calls by it.
+    def paged_prefill_step(
+        params, cache, tok, start, block_tables, length, temps, greedy, keys,
+        rows,
+    ):
+        def gather(x, fill):
+            return x.at[rows].get(mode="fill", fill_value=fill)
+
+        nxt, cache, row_keys = chunk_step(
+            params, cache, tok, gather(start, 0), block_tables, length,
+            gather(temps, 1.0), gather(greedy, True), gather(keys, 0),
+        )
+        out = jnp.zeros(start.shape, nxt.dtype).at[rows].set(nxt, mode="drop")
+        return out, cache, keys.at[rows].set(row_keys, mode="drop")
 
     return paged_prefill_step
 

@@ -241,3 +241,76 @@ def test_lane_calls_grouped_by_spec_name(smoke_setup):
     assert calls.get("pf", 0) > 0  # prompts went through the paged chunk lane
     assert "cb" not in calls and "pfd" not in calls  # dense lanes untouched
     assert rep["kv_dtype"] == "fp32"
+
+
+# ------------------------------------------------- row-bucketed prefill
+def test_row_bucket_fanout_is_l_shaped_and_never_compiles(smoke_setup):
+    """The paged prefill's row count is a semi-static coordinate beside the
+    chunk width: after one warm-up every chunk bucket exists at the full
+    slot count (today's ``pf`` keys) and every smaller row bucket at the
+    top chunk bucket (``pfr``), an L-shaped set, not a product. A stream
+    that moves from one prefilling request to every slot rebinds across
+    them and compiles nothing."""
+    cfg, params = smoke_setup
+    eng = _engine(cfg, params, spec_k=0, token_budget=64)
+    s = 4
+    cb = eng.paged_continuous(slots=s)
+    for c in eng._chunk_buckets():
+        assert ("pf", s, c, "fp32", "1x1") in eng._decode
+    assert eng._row_buckets(s) == [1, 2]
+    assert LANES.get("pfr").fanout(eng, slots=s) == [
+        ("pfr", s, 1, "fp32", "1x1"), ("pfr", s, 2, "fp32", "1x1"),
+    ]
+    for r in (1, 2):
+        assert ("pfr", s, r, "fp32", "1x1") in eng._decode
+    eng.telemetry.enable()
+    reqs = _prompt_reqs(cfg, n=7)
+    for group in (reqs[:1], reqs[1:3], reqs[3:]):  # 1, 2, then 4 prefill
+        cb.admit(group, now=0.0)
+        while cb.has_work:
+            cb.step()
+    assert all(r.done for r in reqs)
+    rows = {e.args["rows"] for e in eng.telemetry.recorder.events()
+            if e.name == "serve.batcher.pack" and e.args["lane"] == "pf"}
+    assert rows == {1, 2, 4}
+    assert cb.stats.prefill_rows < cb.stats.prefill_calls * s
+    assert cb.stats.prefill_rows_padded < cb.stats.prefill_calls * s
+    assert eng.post_warmup_compiles == 0
+    eng.close()
+
+
+def test_spec_lanes_keep_prefill_at_every_slot(smoke_setup):
+    """With spec lanes on, the draft mirror reuses the prefill call's
+    per-slot windows: no row bucket is warmed and every call carries
+    every slot, as before."""
+    cfg, params = smoke_setup
+    eng = _engine(cfg, params, token_budget=64)
+    s = 4
+    cb = eng.paged_continuous(slots=s)
+    assert cb._prefill_rows_dispatch is None
+    for r in eng._row_buckets(s):
+        assert ("pfr", s, r, "fp32", "1x1") not in eng._decode
+    reqs = _prompt_reqs(cfg, n=2)
+    cb.admit(reqs, now=0.0)
+    while cb.has_work:
+        cb.step()
+    assert all(r.done for r in reqs)
+    assert cb.stats.prefill_calls > 0
+    assert cb.stats.prefill_rows_padded == cb.stats.prefill_calls * s
+    eng.close()
+
+
+def test_sharded_pool_gates_off_row_buckets(smoke_setup):
+    """A warmed mesh whose data-parallel size is above 1 shards the page
+    pool: each slot pads with its own shard's null page, so the prefill
+    rows stay on their slots (the stream itself: tests/test_sharding.py)."""
+    from repro.runtime.serve import _WarmCtx
+
+    cfg, params = smoke_setup
+    eng = _engine(cfg, params, spec_k=0, meshes=("2x1",))
+    assert eng.pool_shards == 2
+    assert not eng._row_prefill_enabled(_WarmCtx())
+    eng.close()
+    eng = _engine(cfg, params, spec_k=0)
+    assert eng._row_prefill_enabled(_WarmCtx())
+    eng.close()

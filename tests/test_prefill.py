@@ -151,15 +151,36 @@ class _FakePrefill:
     """Records every chunk row fed: (bucket, start, length, tokens).
 
     The paged prefill executable is batched (``("pf", slots, chunk_bucket,
-    kv_dtype)``): [S]-wide per-row windows, length 0 = idle row. One entry
-    is appended per *real* row, so single-prefill scenarios record exactly
-    what the old B=1 lane did; ``call_rows`` records rows-per-call for the
-    batching assertions.
+    kv_dtype, mesh)``): [S]-wide per-row windows, length 0 = idle row. One
+    entry is appended per *real* row, so single-prefill scenarios record
+    exactly what the old B=1 lane did; ``call_rows`` records rows-per-call
+    for the batching assertions. ``rows`` stands in for the row-bucketed
+    ``("pfr", slots, rows, ...)`` executables; ``row_buckets`` records the
+    row count of each of their calls.
     """
 
     def __init__(self):
         self.calls = []
         self.call_rows = []
+        self.row_buckets = []
+
+    def rows(self, n_rows):
+        def step(cache, tok, start, bt, length, temps, greedy, keys, rows):
+            t, ln, of = np.asarray(tok), np.asarray(length), np.asarray(rows)
+            assert t.shape[0] == n_rows and of.shape == (n_rows,)
+            self.row_buckets.append(n_rows)
+            st = np.asarray(start)
+            nxt = np.zeros(len(st), np.int64)
+            for i in np.flatnonzero(ln > 0):
+                s = int(of[i])
+                self.calls.append(
+                    (t.shape[1], int(st[s]), int(ln[i]),
+                     tuple(int(x) for x in t[i, : int(ln[i])]))
+                )
+                nxt[s] = t[i, ln[i] - 1] + 1
+            self.call_rows.append(int((ln > 0).sum()))
+            return nxt, cache, keys
+        return step
 
     def __call__(self, bucket):
         def step(cache, tok, start, bt, length, temps, greedy, keys):
@@ -181,7 +202,7 @@ class _FakePrefill:
 
 
 def _paged_batcher(pool, *, slots=2, prefill_chunk=16, token_budget=0,
-                   max_pages=8):
+                   max_pages=8, row_buckets=False, telemetry=None):
     fake_pf = _FakePrefill()
     cb = PagedContinuousBatcher(
         dispatch_fn=_fake_decode_dispatch,
@@ -193,6 +214,8 @@ def _paged_batcher(pool, *, slots=2, prefill_chunk=16, token_budget=0,
         prefill_dispatch=fake_pf,
         prefill_chunk=prefill_chunk,
         token_budget=token_budget,
+        prefill_rows_dispatch=fake_pf.rows if row_buckets else None,
+        telemetry=telemetry,
     )
     return cb, fake_pf
 
@@ -442,6 +465,100 @@ def test_batched_paged_prefill_fills_multiple_slots_per_step():
         cb.step(now=2.0)
     assert p1.done and p2.done
     pool.check()
+
+
+def test_prefill_row_counters_and_pack_span():
+    """The prefill call is sized by its chunks: one prompt rides a 1-row
+    call, two a 2-row call, three the full 4-slot call. ``prefill_rows``
+    sums the real rows, ``prefill_rows_padded`` the rows the calls were
+    sized at, and the ``serve.batcher.pack`` span of each prefill call
+    carries its row count."""
+    from repro.core.telemetry import Telemetry
+
+    tel = Telemetry()
+    tel.enable()
+    pool = PagePool(64, 4)
+    cb, pf = _paged_batcher(pool, slots=4, prefill_chunk=16,
+                            token_budget=64, max_pages=16, row_buckets=True,
+                            telemetry=tel)
+
+    def prompt(i):
+        return tuple(range(100 * (i + 1), 100 * (i + 1) + 16))
+
+    reqs = [Request(rid=i, new_tokens=2, greedy=True, prompt=prompt(i))
+            for i in range(6)]
+    for group in (reqs[:1], reqs[1:3], reqs[3:]):
+        assert cb.admit(group, now=0.0) == []
+        cb.step(now=1.0)  # every prompt is one 16-token chunk
+        while cb.has_work:
+            cb.step(now=2.0)
+    assert all(r.done for r in reqs)
+    assert pf.row_buckets == [1, 2]  # packed calls, at the top chunk
+    assert pf.call_rows == [1, 2, 3]  # ...then one full-width call
+    assert cb.stats.prefill_calls == 3
+    assert cb.stats.prefill_rows == 1 + 2 + 3
+    assert cb.stats.prefill_rows_padded == 1 + 2 + 4
+    packs = [e.args["rows"] for e in tel.recorder.events()
+             if e.name == "serve.batcher.pack" and e.args["lane"] == "pf"]
+    assert packs == [1, 2, 4]
+    # every chunk reached the slot its request sat in, tokens intact
+    assert sorted(c[3] for c in pf.calls) == sorted(r.prompt for r in reqs)
+    assert [r.tokens[0] for r in reqs] == [r.prompt[-1] + 1 for r in reqs]
+    pool.check()
+
+
+def test_row_bucketed_prefill_matches_full_width_bitwise():
+    """The ``("pfr", slots, rows, ...)`` executable at two rows against
+    the full-width ``("pf", slots, ...)`` one, fed the same real row at
+    slot 2 over two chunks: the same priming token, every allocatable
+    cache page, and ``new_keys`` for every slot equal; the slots no row
+    names keep their keys bitwise."""
+    from repro.runtime import steps
+
+    cfg = get_config("olmo-1b").smoke()
+    params = models.init_params(cfg, jax.random.PRNGKey(0))
+    S, C, ps, PB, slot = 4, 8, 4, 8, 2
+    full = jax.jit(steps.make_paged_prefill_fn(cfg))
+    by_rows = jax.jit(steps.make_paged_prefill_fn(cfg, by_rows=True))
+    rng = np.random.default_rng(0)
+    prompt = rng.integers(0, cfg.vocab_size, 14).astype(np.int32)
+    table = np.arange(1 + PB, 1 + 2 * PB, dtype=np.int32)  # private pages
+    keys = jnp.asarray(rng.integers(0, 2**32, (S, 2), dtype=np.uint32))
+    temps = np.ones(S, np.float32)
+    greedy = np.array([True, True, False, True])  # slot 2 samples
+    c_full = models.init_paged_cache(cfg, 1 + S * PB, ps)
+    c_rows = models.init_paged_cache(cfg, 1 + S * PB, ps)
+    k_full = k_rows = keys
+    for cursor, n in ((0, 8), (8, 6)):  # the second chunk is padded
+        pos = np.array([5, 3, cursor, 7], np.int32)  # other slots: data
+        tok = np.zeros((S, C), np.int32)
+        tok[slot, :n] = prompt[cursor : cursor + n]
+        bt = np.zeros((S, PB), np.int32)
+        bt[slot] = table
+        length = np.zeros(S, np.int32)
+        length[slot] = n
+        nxt_f, c_full, k_full = full(
+            params, c_full, tok, pos, bt, length, temps, greedy, k_full
+        )
+        rows = np.array([slot, S], np.int32)  # one real row, one pad row
+        nxt_r, c_rows, k_new = by_rows(
+            params, c_rows, tok[[slot, 0]], pos, bt[[slot, 0]],
+            length[[slot, 0]], temps, greedy, k_rows, rows,
+        )
+        assert int(nxt_r[slot]) == int(nxt_f[slot])
+        np.testing.assert_array_equal(np.asarray(k_new), np.asarray(k_full))
+        others = [0, 1, 3]
+        np.testing.assert_array_equal(
+            np.asarray(k_new)[others], np.asarray(k_rows)[others]
+        )
+        assert not np.array_equal(
+            np.asarray(k_new)[slot], np.asarray(k_rows)[slot]
+        )
+        k_rows = k_new
+    for a, b in zip(jax.tree.leaves(c_full), jax.tree.leaves(c_rows)):
+        np.testing.assert_array_equal(
+            np.asarray(a)[:, 1:], np.asarray(b)[:, 1:]
+        )
 
 
 def test_batched_paged_prefill_matches_sequential_chunks(smoke_setup):

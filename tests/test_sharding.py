@@ -290,6 +290,11 @@ def test_paged_mesh_ladder_rebind_zero_compiles():
         assert all(len(r.tokens) == 4 for r in reqs)
         assert eng.post_warmup_compiles == 0, eng.post_warmup_compiles
         assert eng.telemetry.registry.value('mesh_rebinds_total') == 2
+        # a sharded pool keeps every prefill call at every slot
+        assert cb._prefill_rows_dispatch is None
+        st = cb.stats
+        assert st.prefill_calls > 0
+        assert st.prefill_rows_padded == 4 * st.prefill_calls
         print('OK')
     """, devices=4)
     assert "OK" in out

@@ -148,6 +148,25 @@ def test_paged_prefill_step_fits_one_chip(smoke_shapes):
     assert _resident_bytes(compiled) < HBM_LIMIT
 
 
+def test_paged_prefill_rows_step_fits_one_chip(smoke_shapes):
+    """The pfr lane at one row of the smoke's 256-token chunk: the per-slot
+    gather and scatter around the chunk tower, cache donated, in less
+    memory than the full-width pf call."""
+    s = smoke_shapes
+    n, c, cap, r = s["slots"], s["chunk"], s["page_cap"], 1
+    rows = _rows(
+        s, ((r, c), jnp.int32), ((n,), jnp.int32), ((r, cap), jnp.int32),
+        ((r,), jnp.int32), ((n,), jnp.float32), ((n,), jnp.bool_),
+        ((n, 2), jnp.uint32), ((r,), jnp.int32),
+    )
+    step = steps.make_paged_prefill_fn(s["cfg"], by_rows=True)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        s["params"], s["cache"], *rows
+    ).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes > 0
+    assert _resident_bytes(compiled) < HBM_LIMIT
+
+
 def _sds(sharding, shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
