@@ -26,7 +26,7 @@ import sys
 import tempfile
 import time
 from collections import deque
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, fields
 from pathlib import Path
 
 import numpy as np
@@ -41,6 +41,7 @@ if str(ROOT / "src") not in sys.path:
 import check  # noqa: E402
 import counts  # noqa: E402
 import loadgen  # noqa: E402
+import phases  # noqa: E402
 import weights  # noqa: E402
 import devtrace as tracing  # noqa: E402
 
@@ -375,29 +376,52 @@ def _percentile(xs: list, q: float) -> float:
     return float(np.percentile(np.asarray(xs, float), q)) if xs else float("nan")
 
 
-def build_engine(cell: Cell, params):
-    """The program's config and engine for a cell, and its disagg slice."""
+def arch_config(config: dict):
+    """The program's ``ArchConfig`` for a configuration file: a dense SiLU
+    decoder, overlaid with every key of ``model`` that names one of its
+    fields (JSON lists as tuples). ``context`` is the engine's; any other
+    key is refused by name, so a misspelt one cannot serve a dense model."""
     from repro.configs import ArchConfig
-    from repro.runtime.serve import Engine, EngineConfig
 
-    m, sv = cell.model, cell.config["serving"]
-    arch = ArchConfig(
-        name=cell.config["name"], family="dense",
-        num_layers=m["num_layers"], d_model=m["d_model"],
-        num_heads=m["num_heads"], num_kv_heads=m["num_kv_heads"],
-        head_dim=m["head_dim"], d_ff=m["d_ff"], vocab_size=m["vocab_size"],
-        norm=m["norm"], norm_eps=m["norm_eps"], rope_theta=m["rope_theta"],
-        tie_embeddings=m["tie_embeddings"], act="silu", dtype=m["dtype"],
+    known = {f.name for f in fields(ArchConfig)}
+    model = dict(config["model"])
+    model.pop("context")
+    unknown = sorted(set(model) - known)
+    if unknown:
+        raise ValueError(
+            f"{config['name']}: model keys {unknown} name no ArchConfig field"
+        )
+    overlay = {k: tuple(v) if isinstance(v, list) else v for k, v in model.items()}
+    return ArchConfig(
+        **{"name": config["name"], "family": "dense", "act": "silu", **overlay}
     ).validate()
+
+
+def engine_config(config: dict):
+    """The program's ``EngineConfig`` for a configuration file's context
+    and ``serving`` block (``moe_policy`` where it names one)."""
+    from repro.runtime.serve import EngineConfig
+
+    sv = config["serving"]
     disagg = sv.get("disagg")
-    ecfg = EngineConfig(
-        max_len=m["context"], max_batch=sv["slots"],
+    policy = {"moe_policy": sv["moe_policy"]} if "moe_policy" in sv else {}
+    return EngineConfig(
+        max_len=config["model"]["context"], max_batch=sv["slots"],
         page_size=sv["page_size"], num_pages=sv["num_pages"],
         prefill_chunk=sv["prefill_chunk"],
         token_budget=sv.get("token_budget", 0), spec_k=0,
         kv_dtype="fp32", mesh="1x1", meshes=(disagg,) if disagg else (),
+        **policy,
     )
-    return arch, Engine(arch, params, ecfg), disagg
+
+
+def build_engine(cell: Cell, params):
+    """The program's config and engine for a cell, and its disagg slice."""
+    from repro.runtime.serve import Engine
+
+    arch = arch_config(cell.config)
+    disagg = cell.config["serving"].get("disagg")
+    return arch, Engine(arch, params, engine_config(cell.config)), disagg
 
 
 def serve_cell(
@@ -545,6 +569,7 @@ def serve_cell(
                 metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
         result["metrics"] = metrics
         if run.slice is not None:
+            info["program_idle"] = run.slice["program_idle"]
             result["device"]["busy_s"] = run.slice["busy_s"]
             result["device"]["window_s"] = run.slice["window_s"]
             result["breakdown"] = run.slice["breakdown"]
@@ -563,8 +588,11 @@ def _reduce_trace(trace_dir, steps, chips_used, run) -> dict | None:
     """Match the host's step records to the trace's ``bench.step`` spans
     (both end with the window's last step), take each lane's calls that
     started inside a matched step, and total their device time and the
-    work the step's record says they required."""
-    ev = tracing.read_xplane(tracing.find_xplane(trace_dir))
+    work the step's record says they required. The chips' idle time in the
+    matched steps goes to the benchmark's own annotations (``breakdown``)
+    and to the program's layers (``program_idle``, see ``phases``)."""
+    path = tracing.find_xplane(trace_dir)
+    ev = tracing.read_xplane(path)
     host_steps = [e for e in ev.host if e[0] == "bench.step"]
     n = min(len(host_steps), len(steps))
     if n == 0:
@@ -606,6 +634,9 @@ def _reduce_trace(trace_dir, steps, chips_used, run) -> dict | None:
         "window_s": hi - lo,
         "busy_s": sum(busy) / len(busy),
         "lanes": lanes,
+        "program_idle": phases.program_idle(
+            ev.ops, phases.read_program(path), chips_used, lo, hi
+        ),
         "breakdown": {
             "device_ops": tracing.top_ops(clipped.ops, chips_used),
             "idle_gaps": [

@@ -13,7 +13,8 @@ time inside the program's steps the same way, by innermost phase.
 
     python bench/phases.py --workload <name> --seed <n> --seconds <s>
 
-runs one traced run of a cell as ``run.py --trace 1`` does and prints its
+runs one traced run of a cell as ``run.py --trace 1`` does, whose slice
+already holds ``program_idle`` (``harness._reduce_trace``), and prints its
 result line with ``program_idle`` and ``program_host`` added to it. Needs a
 TPU, as ``run.py`` does. ``--save <file>`` also writes the trace's last
 steps (every event, program spans included) as JSON of at most 300 KB, the
@@ -143,14 +144,10 @@ def probe(cell, seed: int, seconds: float, save=None, **kw) -> dict:
         if sl is not None:
             path = devtrace.find_xplane(trace_dir)
             program = read_program(path)
-            ev = devtrace.read_xplane(path)
-            sl["program_idle"] = program_idle(
-                ev.ops, program, chips_used, sl["lo"], sl["hi"]
-            )
             sl["program_host"] = program_host(program, sl["lo"], sl["hi"])
             slices.append(sl)
             if save:
-                save_steps(save, ev, program)
+                save_steps(save, devtrace.read_xplane(path), program)
         return sl
 
     harness._reduce_trace = reduce_with_program

@@ -73,11 +73,20 @@ def test_traced_run_reports_per_layer_metrics():
     res = serve(tiny_cell(), trace=True)
     assert res["correct"] is True
     for name in ("host_ms_per_step", "queue_wait_p90_ms",
-                 "enqueue_ms_per_call", "pages_peak_pct"):
+                 "enqueue_ms_per_call", "pages_peak_pct", "batcher_idle_ms",
+                 "dispatch_idle_ms", "cache_idle_ms"):
         assert res["metrics"][name]["value"] > 0, name
     assert 0 < res["metrics"]["pages_peak_pct"]["value"] <= 100
     assert res["device"]["window_s"] > 0
     assert "idle_gaps" in res["breakdown"]
+    # no chip ops on the CPU: the layers' idle, times the steps, and the
+    # idle outside every program span make up the whole slice
+    idle = res["info"]["program_idle"]
+    layers = sum(res["metrics"][f"{k}_idle_ms"]["value"]
+                 for k in ("batcher", "dispatch", "cache"))
+    assert layers * idle["steps"] / 1e3 + idle["none"] == pytest.approx(
+        res["metrics"]["device_idle_pct.chat"]["value"] / 100
+        * res["device"]["window_s"], rel=1e-9)
 
 
 def alter_tokens(cb):

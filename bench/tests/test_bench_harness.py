@@ -177,3 +177,51 @@ def test_run_requires_its_arguments(flag):
     p = _run(["bench/run.py", *argv], ROOT)
     assert p.returncode != 0
     assert "{" not in p.stdout
+
+
+# Each benchmark configuration's ArchConfig as the harness built it from a
+# fixed list of dense keys, before it took every field from the file.
+BUILT_BEFORE = {
+    "olmo-1b": dict(
+        family="dense", num_layers=16, d_model=2048, num_heads=16,
+        num_kv_heads=16, head_dim=128, d_ff=8192, vocab_size=50304,
+        norm="ln_nonparam", norm_eps=1e-05, rope_theta=10000.0,
+        tie_embeddings=True, act="silu", dtype="bfloat16",
+    ),
+    "deepseek-67b": dict(
+        family="dense", num_layers=4, d_model=8192, num_heads=64,
+        num_kv_heads=8, head_dim=128, d_ff=22016, vocab_size=102400,
+        norm="rmsnorm", norm_eps=1e-06, rope_theta=10000.0,
+        tie_embeddings=False, act="silu", dtype="bfloat16",
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(BUILT_BEFORE))
+def test_arch_config_is_field_for_field_as_before(name):
+    from repro.configs import ArchConfig
+
+    config = json.loads((BENCH / "configs" / f"{name}.json").read_text())
+    assert harness.arch_config(config) == ArchConfig(
+        name=name, **BUILT_BEFORE[name]
+    )
+    ecfg = harness.engine_config(config)
+    assert ecfg.moe_policy == "drop"  # the program's default, untouched
+    assert ecfg.max_len == config["model"]["context"]
+
+
+def test_patterned_file_reaches_the_program():
+    config = json.loads((BENCH / "tests" / "data" / "tiny-patterned.json").read_text())
+    arch = harness.arch_config(config)
+    assert arch.layer_pattern == ("attn_local",) * 3 + ("attn",)
+    assert arch.mlp_pattern == ("moe",) and arch.period == 4
+    assert (arch.sliding_window, arch.num_experts, arch.top_k,
+            arch.expert_d_ff) == (24, 4, 2, 32)
+    assert harness.engine_config(config).moe_policy == "dense"
+
+
+def test_an_unknown_model_key_is_refused_by_name():
+    config = json.loads((BENCH / "tests" / "data" / "tiny.json").read_text())
+    config["model"]["slidng_window"] = 24
+    with pytest.raises(ValueError, match="slidng_window"):
+        harness.arch_config(config)
