@@ -437,11 +437,64 @@ def init_paged_kv_cache(
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
 
+def _paged_write_gather(
+    cfg: ArchConfig,
+    cache: dict,
+    layer: jax.Array,
+    wpage: jax.Array,
+    woff: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    bt: jax.Array,
+) -> tuple[jax.Array, jax.Array, dict]:
+    """Write new K/V rows into the stacked pool and gather each row's pages.
+
+    ``cache`` leaves are the whole stack's ``[m, P, page_size, ...]`` pool;
+    ``layer`` picks this layer. The write is one scatter at ``(layer,
+    wpage, woff)`` into the stacked leaf, and the read one gather over
+    ``(layer, bt)``: no layer's pool is sliced out or written back, so the
+    pool riding the layer scan's carry is updated in place (DESIGN.md §9).
+    ``k``/``v`` rows are ``[*wpage.shape, KH, dh]``. Returns each row's
+    logical view ``[B, pages_bucket * page_size, KH, dh]`` of keys and
+    values, and the updated stacked cache.
+
+    With an int8 cache (DESIGN.md §12) the write quantises each row
+    (``quantise_kv_rows``) and scatters row + scale; the read gathers pages
+    *and* scales and dequantises. The branch is on the cache's abstract
+    dtype — trace-time, one executable per ``kv_dtype`` coordinate.
+    """
+    b, pages_bucket = bt.shape
+    seq = pages_bucket * cache["k"].shape[2]
+
+    def view(t):
+        return t.reshape(b, seq, cfg.num_kv_heads, cfg.head_dim)
+
+    if cache["k"].dtype == jnp.int8:  # trace-time: dtype is a dispatch key
+        qk, ksc = quantise_kv_rows(k)
+        qv, vsc = quantise_kv_rows(v)
+        new = {
+            "k": cache["k"].at[layer, wpage, woff].set(qk),
+            "v": cache["v"].at[layer, wpage, woff].set(qv),
+            "k_scale": cache["k_scale"].at[layer, wpage, woff].set(ksc),
+            "v_scale": cache["v_scale"].at[layer, wpage, woff].set(vsc),
+        }
+        gk = dequantise_kv_rows(new["k"][layer, bt], new["k_scale"][layer, bt])
+        gv = dequantise_kv_rows(new["v"][layer, bt], new["v_scale"][layer, bt])
+    else:
+        new = {
+            "k": cache["k"].at[layer, wpage, woff].set(k),
+            "v": cache["v"].at[layer, wpage, woff].set(v),
+        }
+        gk, gv = new["k"][layer, bt], new["v"][layer, bt]
+    return view(gk), view(gv), new
+
+
 def paged_decode_attention(
     cfg: ArchConfig,
     p: dict,
     x: jax.Array,
     cache: dict,
+    layer: jax.Array,
     pos: jax.Array,
     block_tables: jax.Array,
     *,
@@ -449,65 +502,39 @@ def paged_decode_attention(
 ) -> tuple[jax.Array, dict]:
     """One-token decode through a paged KV cache.
 
-    x: [B,1,D]; cache k/v: [P, page_size, KH, dh] (pooled pages);
-    ``block_tables``: i32[B, pages_bucket] page ids mapping each row's
-    logical positions onto physical pages (0 = the null page); ``pos``:
-    i32[B] per-row positions.
+    x: [B,1,D]; cache k/v: [m, P, page_size, KH, dh] (the stacked pooled
+    pages of every repeat of this period slot); ``layer``: i32 scalar, the
+    repeat this call reads and writes; ``block_tables``: i32[B,
+    pages_bucket] page ids mapping each row's logical positions onto
+    physical pages (0 = the null page); ``pos``: i32[B] per-row positions.
 
-    The write is a scatter into ``pages[bt[b, pos//ps], pos%ps]``; the hot
-    loop never checks capacity — the table's width (``pages_bucket``) is a
-    compile-time constant, and growing past it is a cold-path rebind to the
-    next bucket's executable (DESIGN.md §9). Inactive slots carry all-null
-    tables so their writes land in the null page, which no live table
-    references. The read is a page gather; positions past ``pos`` (incl.
-    whatever garbage the null page holds) are masked exactly like the dense
-    per-row path, so paged and dense decode agree bit-for-bit.
-
-    With an int8 cache (DESIGN.md §12) the write quantises each new K/V row
-    (per-row absmax scale, ``quantise_kv_rows``) and scatters row + scale;
-    the read gathers pages *and* scales and dequantises before the shared
-    SDPA tail. The branch is on the cache's abstract dtype — trace-time,
-    one executable per ``kv_dtype`` coordinate, never a hot-loop check.
+    The write is a scatter into ``pages[layer, bt[b, pos//ps], pos%ps]``;
+    the hot loop never checks capacity — the table's width
+    (``pages_bucket``) is a compile-time constant, and growing past it is a
+    cold-path rebind to the next bucket's executable (DESIGN.md §9).
+    Inactive slots carry all-null tables so their writes land in the null
+    page, which no live table references. The read is a page gather;
+    positions past ``pos`` (incl. whatever garbage the null page holds) are
+    masked exactly like the dense per-row path, so paged and dense decode
+    agree bit-for-bit. Both go through ``_paged_write_gather``, int8 pools
+    included.
 
     This pure-JAX gather + SDPA is what runs on every backend, the TPU
     included. ``kernels.paged_decode_attention`` computes the same thing
     in place but is not called from here: the TPU compiler refuses its
     page block (``tests/test_tpu_compile.py``).
     """
-    b = x.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
     bt = jnp.asarray(block_tables, jnp.int32)
-    num_pages, ps = cache["k"].shape[:2]
-    pages_bucket = bt.shape[1]
-    positions = pos[:, None]
-    q, k, v = _qkv(cfg, p, x, positions)
+    ps = cache["k"].shape[2]
+    q, k, v = _qkv(cfg, p, x, pos[:, None])
     q = hint(q, "batch", None, None, None)
     # ---- write: scatter the new K/V row into each request's current page
-    page_idx = jnp.clip(pos // ps, 0, pages_bucket - 1)
+    page_idx = jnp.clip(pos // ps, 0, bt.shape[1] - 1)
     wpage = jnp.take_along_axis(bt, page_idx[:, None], axis=1)[:, 0]
-    woff = pos % ps
-    seq = pages_bucket * ps
-    if cache["k"].dtype == jnp.int8:  # trace-time: dtype is a dispatch key
-        qk, ksc = quantise_kv_rows(k[:, 0])
-        qv, vsc = quantise_kv_rows(v[:, 0])
-        ck = cache["k"].at[wpage, woff].set(qk)
-        cv = cache["v"].at[wpage, woff].set(qv)
-        cks = cache["k_scale"].at[wpage, woff].set(ksc)
-        cvs = cache["v_scale"].at[wpage, woff].set(vsc)
-        gk = dequantise_kv_rows(ck[bt], cks[bt]).reshape(
-            b, seq, cfg.num_kv_heads, cfg.head_dim
-        )
-        gv = dequantise_kv_rows(cv[bt], cvs[bt]).reshape(
-            b, seq, cfg.num_kv_heads, cfg.head_dim
-        )
-        new_cache = {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
-    else:
-        ck = cache["k"].at[wpage, woff].set(k[:, 0])
-        cv = cache["v"].at[wpage, woff].set(v[:, 0])
-        # ---- read: gather each request's pages into its logical view
-        gk = ck[bt].reshape(b, seq, cfg.num_kv_heads, cfg.head_dim)
-        gv = cv[bt].reshape(b, seq, cfg.num_kv_heads, cfg.head_dim)
-        new_cache = {"k": ck, "v": cv}
+    gk, gv, new_cache = _paged_write_gather(
+        cfg, cache, layer, wpage, pos % ps, k[:, 0], v[:, 0], bt
+    )
     return _decode_sdpa_rows(cfg, p, q, gk, gv, pos, local=local), new_cache
 
 
@@ -517,6 +544,7 @@ def paged_prefill_attention(
     p: dict,
     x: jax.Array,
     cache: dict,
+    layer: jax.Array,
     start: jax.Array,
     block_tables: jax.Array,
     length: jax.Array,
@@ -525,10 +553,11 @@ def paged_prefill_attention(
 ) -> tuple[jax.Array, dict]:
     """Chunk-of-C-tokens prompt ingestion through the paged KV cache.
 
-    x: [B,C,D] chunk embeddings; cache k/v: [P, page_size, KH, dh];
-    ``start``: i32[B] logical position of each row's first chunk token;
-    ``length``: i32[B] real tokens in the chunk (columns >= length are
-    bucket padding); ``block_tables``: i32[B, pages_bucket].
+    x: [B,C,D] chunk embeddings; cache k/v: [m, P, page_size, KH, dh] and
+    ``layer`` as in ``paged_decode_attention``; ``start``: i32[B] logical
+    position of each row's first chunk token; ``length``: i32[B] real
+    tokens in the chunk (columns >= length are bucket padding);
+    ``block_tables``: i32[B, pages_bucket].
 
     Scatter-writes all C new K/V positions through the block table in one
     step — padded columns are redirected to the null page 0, so bucket
@@ -538,52 +567,32 @@ def paged_prefill_attention(
     own K/V is read back from the pages it just wrote). Bit-for-bit equal
     on CPU to C iterations of ``paged_decode_attention``: future chunk rows
     are masked to exactly-zero probability, so their (different) garbage
-    contributes exactly 0.0 to every softmax sum (DESIGN.md §10).
+    contributes exactly 0.0 to every softmax sum (DESIGN.md §10). The int8
+    write uses the same per-row scales as the decode scatter, so int8
+    chunked ingestion writes the same bits as int8 token-by-token.
 
     C (the chunk bucket) is a compile-time constant — the semi-static chunk
     key ``("pf", slots, chunk_bucket, kv_dtype)`` — so chunk-size variation
     dispatches on the cold path and never branches per step.
     """
-    b, c = x.shape[:2]
+    c = x.shape[1]
     start = jnp.asarray(start, jnp.int32)
     length = jnp.asarray(length, jnp.int32)
     bt = jnp.asarray(block_tables, jnp.int32)
-    _, ps = cache["k"].shape[:2]
-    pages_bucket = bt.shape[1]
+    ps = cache["k"].shape[2]
     offs = jnp.arange(c, dtype=jnp.int32)
     positions = start[:, None] + offs[None, :]  # [B,C]
     q, k, v = _qkv(cfg, p, x, positions)
     q = hint(q, "batch", None, None, None)
     # ---- write: scatter every real chunk row through the block table;
     # padded rows land in the reserved null page (id 0).
-    page_idx = jnp.clip(positions // ps, 0, pages_bucket - 1)
+    page_idx = jnp.clip(positions // ps, 0, bt.shape[1] - 1)
     wpage = jnp.take_along_axis(bt, page_idx, axis=1)  # [B,C]
     wpage = jnp.where(offs[None, :] < length[:, None], wpage, 0)
-    woff = positions % ps
-    seq = pages_bucket * ps
-    if cache["k"].dtype == jnp.int8:  # trace-time: dtype is a dispatch key
-        # per-row scales, identical math to the decode scatter — int8
-        # chunked ingestion writes the same bits as int8 token-by-token
-        qk, ksc = quantise_kv_rows(k)
-        qv, vsc = quantise_kv_rows(v)
-        ck = cache["k"].at[wpage, woff].set(qk)
-        cv = cache["v"].at[wpage, woff].set(qv)
-        cks = cache["k_scale"].at[wpage, woff].set(ksc)
-        cvs = cache["v_scale"].at[wpage, woff].set(vsc)
-        gk = dequantise_kv_rows(ck[bt], cks[bt]).reshape(
-            b, seq, cfg.num_kv_heads, cfg.head_dim
-        )
-        gv = dequantise_kv_rows(cv[bt], cvs[bt]).reshape(
-            b, seq, cfg.num_kv_heads, cfg.head_dim
-        )
-        new_cache = {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
-    else:
-        ck = cache["k"].at[wpage, woff].set(k)
-        cv = cache["v"].at[wpage, woff].set(v)
-        # ---- read: gather pages, mask per query row (causal in the chunk)
-        gk = ck[bt].reshape(b, seq, cfg.num_kv_heads, cfg.head_dim)
-        gv = cv[bt].reshape(b, seq, cfg.num_kv_heads, cfg.head_dim)
-        new_cache = {"k": ck, "v": cv}
+    # ---- read: gather pages, mask per query row (causal in the chunk)
+    gk, gv, new_cache = _paged_write_gather(
+        cfg, cache, layer, wpage, positions % ps, k, v, bt
+    )
     return (
         _decode_sdpa_rows(cfg, p, q, gk, gv, positions, local=local),
         new_cache,

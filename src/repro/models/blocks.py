@@ -146,12 +146,15 @@ def block_paged_decode(
     p: dict,
     x: jax.Array,
     cache: dict,
+    layer: jax.Array,
     pos: jax.Array,
     block_tables: jax.Array,
     *,
     moe_policy: str = "drop",
 ) -> tuple[jax.Array, dict]:
-    """Single-token block step through the paged KV cache (DESIGN.md §9)."""
+    """Single-token block step through the paged KV cache (DESIGN.md §9).
+    ``cache`` is the slot's stacked ``[m, ...]`` pool; ``layer`` the repeat
+    this step reads and writes in place. Returns the updated stack."""
     mixer = cfg.mixer_at(slot)
     h = norm_apply(cfg, p["norm1"], x)
     if not mixer.startswith("attn"):
@@ -160,7 +163,7 @@ def block_paged_decode(
             f"attention-only (see block_paged_cache_init)."
         )
     h, cache = attn.paged_decode_attention(
-        cfg, p["attn"], h, cache, pos, block_tables,
+        cfg, p["attn"], h, cache, layer, pos, block_tables,
         local=(mixer == "attn_local"),
     )
     x = x + h
@@ -174,13 +177,15 @@ def block_paged_prefill(
     p: dict,
     x: jax.Array,
     cache: dict,
+    layer: jax.Array,
     start: jax.Array,
     block_tables: jax.Array,
     length: jax.Array,
     *,
     moe_policy: str = "drop",
 ) -> tuple[jax.Array, dict]:
-    """Chunked-prefill block step through the paged KV cache (DESIGN.md §10)."""
+    """Chunked-prefill block step through the paged KV cache (DESIGN.md §10).
+    ``cache`` and ``layer`` as in ``block_paged_decode``."""
     mixer = cfg.mixer_at(slot)
     h = norm_apply(cfg, p["norm1"], x)
     if not mixer.startswith("attn"):
@@ -189,7 +194,7 @@ def block_paged_prefill(
             f"attention-only (see block_paged_cache_init)."
         )
     h, cache = attn.paged_prefill_attention(
-        cfg, p["attn"], h, cache, start, block_tables, length,
+        cfg, p["attn"], h, cache, layer, start, block_tables, length,
         local=(mixer == "attn_local"),
     )
     x = x + h

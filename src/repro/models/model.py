@@ -244,6 +244,38 @@ def init_paged_cache(
     return caches
 
 
+def _paged_scan(
+    cfg: ArchConfig, params: dict, cache: list, x: jax.Array, block
+) -> tuple[jax.Array, list]:
+    """Run the stack over the paged pool with the pool in the scan's carry.
+
+    The stacked per-slot pools ride the carry beside ``x`` and each layer
+    writes its rows in place at ``(layer, page, offset)``; nothing comes out
+    as ``ys``. A pool scanned as ``xs`` with the new pool as ``ys`` would
+    slice every layer's whole pool out, update it and write it back, then
+    copy the fresh stack into the donated buffer: whole-pool traffic on
+    every call (DESIGN.md §9). ``block(slot, p, x, cache, layer)`` is one
+    block step returning ``(x, cache)``.
+    """
+    m = cfg.num_layers // cfg.period
+
+    def body(carry, slots):
+        x, caches = carry
+        slot_params, layer = slots
+        caches = list(caches)
+        for slot in range(cfg.period):
+            x, caches[slot] = block(
+                slot, slot_params[slot], x, caches[slot], layer
+            )
+        return (x, tuple(caches)), None
+
+    (x, new_cache), _ = jax.lax.scan(
+        body, (x, tuple(cache)),
+        (tuple(params["blocks"]), jnp.arange(m, dtype=jnp.int32)),
+    )
+    return x, list(new_cache)
+
+
 def paged_decode_step(
     cfg: ArchConfig,
     params: dict,
@@ -262,23 +294,18 @@ def paged_decode_step(
     """
     x = embed_apply(cfg, params["embed"], inputs)
 
-    def body(x, slots):
-        slot_params, slot_caches = slots
-        new_caches = []
-        for slot in range(cfg.period):
-            x, c = block_paged_decode(
-                cfg, slot, slot_params[slot], x, slot_caches[slot], pos,
-                block_tables, moe_policy=moe_policy,
-            )
-            new_caches.append(c)
-        return x, tuple(new_caches)
+    def block(slot, p, x, c, layer):
+        return block_paged_decode(
+            cfg, slot, p, x, c, layer, pos, block_tables,
+            moe_policy=moe_policy,
+        )
 
-    x, new_cache = jax.lax.scan(body, x, (tuple(params["blocks"]), tuple(cache)))
+    x, new_cache = _paged_scan(cfg, params, cache, x, block)
     from .layers import norm_apply
 
     x = norm_apply(cfg, params["final_norm"], x)
     logits = head_apply(cfg, params["head"], params["embed"], x[:, -1])
-    return logits, list(new_cache)
+    return logits, new_cache
 
 
 def _last_real_row(x: jax.Array, length: jax.Array) -> jax.Array:
@@ -307,21 +334,16 @@ def _paged_chunk_hidden(
     callers differ only in which rows they project to logits."""
     x = embed_apply(cfg, params["embed"], inputs)
 
-    def body(x, slots):
-        slot_params, slot_caches = slots
-        new_caches = []
-        for slot in range(cfg.period):
-            x, c = block_paged_prefill(
-                cfg, slot, slot_params[slot], x, slot_caches[slot], start,
-                block_tables, length, moe_policy=moe_policy,
-            )
-            new_caches.append(c)
-        return x, tuple(new_caches)
+    def block(slot, p, x, c, layer):
+        return block_paged_prefill(
+            cfg, slot, p, x, c, layer, start, block_tables, length,
+            moe_policy=moe_policy,
+        )
 
-    x, new_cache = jax.lax.scan(body, x, (tuple(params["blocks"]), tuple(cache)))
+    x, new_cache = _paged_scan(cfg, params, cache, x, block)
     from .layers import norm_apply
 
-    return norm_apply(cfg, params["final_norm"], x), list(new_cache)
+    return norm_apply(cfg, params["final_norm"], x), new_cache
 
 
 def paged_prefill_step(

@@ -213,29 +213,53 @@ def test_pool_invariants_random_ops(ops):
 
 
 # ------------------------------------------- paged vs dense decode (CPU bit)
-def test_paged_decode_matches_dense_bitwise():
-    cfg = get_config("olmo-1b").smoke()
+@pytest.mark.parametrize(
+    "arch,layers",
+    [
+        ("olmo-1b", None),
+        # two repeats of a windowed + full period: the layer index takes
+        # more than one value and the two mixers alternate
+        ("gemma2-27b", 4),
+    ],
+)
+def test_paged_decode_matches_dense_bitwise(arch, layers):
+    from dataclasses import replace
+
+    cfg = get_config(arch).smoke()
+    if layers is not None:
+        cfg = replace(cfg, num_layers=layers).validate()
     params = models.init_params(cfg, jax.random.PRNGKey(0))
-    S, max_len, ps = 3, 32, 8
+    S, max_len, ps, steps = 3, 32, 8, 20
     PB = max_len // ps
     dense = models.init_cache(cfg, S, max_len)
     paged = models.init_paged_cache(cfg, 1 + S * PB, ps)
-    bt = jnp.asarray(
-        1 + np.arange(S * PB).reshape(S, PB), jnp.int32
-    )  # identity layout
     rng = np.random.default_rng(0)
+    # shuffled layout: each row's pages are scattered over the pool
+    bt = jnp.asarray(
+        1 + rng.permutation(S * PB).reshape(S, PB), jnp.int32
+    )
     tok = jnp.asarray(rng.integers(0, cfg.vocab_size, (S, 1)), jnp.int32)
     pos = jnp.zeros((S,), jnp.int32)
     dstep = jax.jit(lambda p, c, t, po: models.decode_step(cfg, p, c, t, po))
     pstep = jax.jit(
         lambda p, c, t, po, b: models.paged_decode_step(cfg, p, c, t, po, b)
     )
-    for _ in range(6):
+    for _ in range(steps):
         ld, dense = dstep(params, dense, tok, pos)
         lp, paged = pstep(params, paged, tok, pos, bt)
         np.testing.assert_array_equal(np.asarray(ld), np.asarray(lp))
         tok = jnp.argmax(ld, axis=-1).astype(jnp.int32)[:, None]
         pos = pos + 1
+    # every layer's pages, read through the tables, hold the dense rows:
+    # a write that lands in another layer (or page) fails here
+    for slot in range(cfg.period):
+        for leaf in ("k", "v"):
+            pool = np.asarray(paged[slot][leaf])  # [m, P, ps, KH, dh]
+            rows = np.asarray(dense[slot][leaf])  # [m, S, max_len, KH, dh]
+            view = pool[:, np.asarray(bt)].reshape(rows.shape)
+            np.testing.assert_array_equal(
+                view[:, :, :steps], rows[:, :, :steps]
+            )
 
 
 def test_paged_kernel_matches_oracle():

@@ -10,6 +10,7 @@ tests and only the worker that runs this file loads the TPU library.
 
 import importlib.util
 import os
+import re
 from pathlib import Path
 
 import jax
@@ -113,58 +114,103 @@ def _rows(s, *shapes):
     return s["place"](tuple(jax.ShapeDtypeStruct(sh, dt) for sh, dt in shapes))
 
 
-def test_paged_decode_step_fits_one_chip(smoke_shapes):
+def _lane(s, lane):
+    """One paged lane's step function and its row inputs at smoke size:
+    cbp at the widest pages bucket, pf at the 256-token chunk, pfr at one
+    row of that chunk."""
+    n, c, cap, pb = s["slots"], s["chunk"], s["page_cap"], s["pages_bucket"]
+    tail = (((n,), jnp.float32), ((n,), jnp.bool_), ((n, 2), jnp.uint32))
+    if lane == "cbp":
+        return steps.make_paged_slot_decode_fn(s["cfg"]), _rows(
+            s, ((n, 1), jnp.int32), ((n,), jnp.int32), ((n, pb), jnp.int32),
+            ((n,), jnp.bool_), *tail,
+        )
+    if lane == "pf":
+        return steps.make_paged_prefill_fn(s["cfg"]), _rows(
+            s, ((n, c), jnp.int32), ((n,), jnp.int32), ((n, cap), jnp.int32),
+            ((n,), jnp.int32), *tail,
+        )
+    r = 1
+    return steps.make_paged_prefill_fn(s["cfg"], by_rows=True), _rows(
+        s, ((r, c), jnp.int32), ((n,), jnp.int32), ((r, cap), jnp.int32),
+        ((r,), jnp.int32), *tail, ((r,), jnp.int32),
+    )
+
+
+@pytest.fixture(scope="module")
+def compiled_lane(smoke_shapes):
+    """Each paged lane compiled once for the chip, cache donated."""
+    s, done = smoke_shapes, {}
+
+    def get(lane):
+        if lane not in done:
+            step, rows = _lane(s, lane)
+            done[lane] = jax.jit(step, donate_argnums=(1,)).lower(
+                s["params"], s["cache"], *rows
+            ).compile()
+        return done[lane]
+
+    return get
+
+
+# An HLO instruction's result shape and opcode: ``%x = bf16[16,1025,...]{..}
+# dynamic-update-slice(...``. The layout braces may hold tiling, not spaces.
+_HLO_OP = re.compile(r"=\s*\w+\[([\d,]*)\]\S*\s+([\w-]+)\(")
+_POOL_TRAFFIC = ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice")
+
+
+def _pool_traffic(hlo: str, pool_shape) -> list[str]:
+    """Instructions (fused ones included) that copy, slice out or write
+    back the whole stacked pool or one layer's pool."""
+    stacked, layer = tuple(pool_shape), tuple(pool_shape[1:])
+    found = []
+    for line in hlo.splitlines():
+        m = _HLO_OP.search(line)
+        if not m or m.group(2) not in _POOL_TRAFFIC or not m.group(1):
+            continue
+        dims = tuple(int(d) for d in m.group(1).split(","))
+        while len(dims) > len(layer) and dims[0] == 1:
+            dims = dims[1:]  # a layer sliced out keeps a leading 1
+        if dims in (stacked, layer):
+            found.append(line.strip())
+    return found
+
+
+def test_paged_decode_step_fits_one_chip(compiled_lane):
     """The cbp lane at its widest pages bucket, cache donated."""
-    s = smoke_shapes
-    n, pb = s["slots"], s["pages_bucket"]
-    rows = _rows(
-        s, ((n, 1), jnp.int32), ((n,), jnp.int32), ((n, pb), jnp.int32),
-        ((n,), jnp.bool_), ((n,), jnp.float32), ((n,), jnp.bool_),
-        ((n, 2), jnp.uint32),
-    )
-    step = steps.make_paged_slot_decode_fn(s["cfg"])
-    compiled = jax.jit(step, donate_argnums=(1,)).lower(
-        s["params"], s["cache"], *rows
-    ).compile()
+    compiled = compiled_lane("cbp")
     assert compiled.memory_analysis().alias_size_in_bytes > 0
     assert _resident_bytes(compiled) < HBM_LIMIT
 
 
-def test_paged_prefill_step_fits_one_chip(smoke_shapes):
+def test_paged_prefill_step_fits_one_chip(smoke_shapes, compiled_lane):
     """The pf lane at the smoke's 256-token chunk, cache donated."""
-    s = smoke_shapes
-    n, c, cap = s["slots"], s["chunk"], s["page_cap"]
-    assert c == chip_smoke.PREFILL_CHUNK
-    rows = _rows(
-        s, ((n, c), jnp.int32), ((n,), jnp.int32), ((n, cap), jnp.int32),
-        ((n,), jnp.int32), ((n,), jnp.float32), ((n,), jnp.bool_),
-        ((n, 2), jnp.uint32),
-    )
-    step = steps.make_paged_prefill_fn(s["cfg"])
-    compiled = jax.jit(step, donate_argnums=(1,)).lower(
-        s["params"], s["cache"], *rows
-    ).compile()
+    assert smoke_shapes["chunk"] == chip_smoke.PREFILL_CHUNK
+    compiled = compiled_lane("pf")
     assert compiled.memory_analysis().alias_size_in_bytes > 0
     assert _resident_bytes(compiled) < HBM_LIMIT
 
 
-def test_paged_prefill_rows_step_fits_one_chip(smoke_shapes):
+def test_paged_prefill_rows_step_fits_one_chip(compiled_lane):
     """The pfr lane at one row of the smoke's 256-token chunk: the per-slot
     gather and scatter around the chunk tower, cache donated, in less
     memory than the full-width pf call."""
-    s = smoke_shapes
-    n, c, cap, r = s["slots"], s["chunk"], s["page_cap"], 1
-    rows = _rows(
-        s, ((r, c), jnp.int32), ((n,), jnp.int32), ((r, cap), jnp.int32),
-        ((r,), jnp.int32), ((n,), jnp.float32), ((n,), jnp.bool_),
-        ((n, 2), jnp.uint32), ((r,), jnp.int32),
-    )
-    step = steps.make_paged_prefill_fn(s["cfg"], by_rows=True)
-    compiled = jax.jit(step, donate_argnums=(1,)).lower(
-        s["params"], s["cache"], *rows
-    ).compile()
+    compiled = compiled_lane("pfr")
     assert compiled.memory_analysis().alias_size_in_bytes > 0
     assert _resident_bytes(compiled) < HBM_LIMIT
+
+
+@pytest.mark.parametrize("lane", ["cbp", "pf", "pfr"])
+def test_paged_lane_writes_pool_in_place(smoke_shapes, compiled_lane, lane):
+    """The pool rides the layer scan's carry and is written in place: the
+    lane's temporaries stay below one stacked pool leaf, and no copy,
+    dynamic-slice or dynamic-update-slice of the pool or of one layer's
+    pool is left in the compiled program (DESIGN.md §9)."""
+    leaf = smoke_shapes["cache"][0]["k"]
+    compiled = compiled_lane(lane)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < leaf.size * leaf.dtype.itemsize
+    assert _pool_traffic(compiled.as_text(), leaf.shape) == []
 
 
 def _sds(sharding, shape, dtype=jnp.bfloat16):
